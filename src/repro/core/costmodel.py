@@ -55,11 +55,30 @@ PF_LOW = HardwareProfile(
     name="PF-Low", gpu_flops=30e12, gpu_mem=12 * GB, gpu_hbm_bw=768e9,
     cpu_mem=176 * GB, pcie_bw=10e9, disk_read_bw=0.30e9, cpu_flops=0.9e12,
     disk_raw_bw=2.0e9)
-# TPU target for the scale-out deployment (per chip).
+# TPU target for the scale-out deployment (per chip).  Peaks: Google
+# Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s); gpu_flops is derated like the GPU profiles above.
 TPU_V5E_HOST = HardwareProfile(
     name="TPU-v5e", gpu_flops=197e12 * 0.55, gpu_mem=16 * GB,
     gpu_hbm_bw=819e9, cpu_mem=192 * GB, pcie_bw=15e9, disk_read_bw=2.0e9,
     cpu_flops=1.0e12)
+
+# The profile the served path prices its market with, keyed by the
+# accelerator's ``jax.Device.device_kind``.
+DEVICE_PROFILES = {
+    "TPU v5 lite": TPU_V5E_HOST,
+}
+
+
+def profile_for_device(device_kind: str) -> HardwareProfile:
+    """The :data:`DEVICE_PROFILES` entry for ``device_kind``; an unknown
+    kind is an error, never another device's profile."""
+    try:
+        return DEVICE_PROFILES[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no hardware profile for device kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_PROFILES)}") from None
 
 
 # bytes per stored KV element for each pool format (mirrors

@@ -81,7 +81,31 @@ class WorkerStats:
     batch_log: List[Dict[str, float]] = field(default_factory=list)
 
 
-class PipelineWorker(threading.Thread):
+class _StageWorker(threading.Thread):
+    """Thread shell shared by both stage workers: runs ``_loop`` until
+    stopped, and records an exception that escapes it in ``error``
+    (then calls ``on_error``) so a waiter can re-raise it at once
+    instead of timing out on work that will never finish."""
+
+    def __init__(self, name: str,
+                 on_error: Optional[Callable[[BaseException], None]]):
+        super().__init__(name=name, daemon=True)
+        self.on_error = on_error
+        self.error: Optional[BaseException] = None
+
+    def _loop(self) -> None:
+        raise NotImplementedError
+
+    def run(self) -> None:
+        try:
+            self._loop()
+        except Exception as e:     # thread boundary: record and report
+            self.error = e
+            if self.on_error is not None:
+                self.on_error(e)
+
+
+class PipelineWorker(_StageWorker):
     """One pipeline stage: forms batches by backlog, processes, forwards.
 
     ``process_fn(items) -> outputs`` runs under this worker's own lock;
@@ -94,8 +118,9 @@ class PipelineWorker(threading.Thread):
                  process_fn: Callable[[List[Any]], List[Any]],
                  scheduler: BacklogScheduler,
                  on_batch_boundary: Optional[Callable[[], None]] = None,
-                 idle_wait: float = 0.01):
-        super().__init__(name=name, daemon=True)
+                 idle_wait: float = 0.01,
+                 on_error: Optional[Callable[[BaseException], None]] = None):
+        super().__init__(name, on_error)
         self.in_queue = in_queue
         self.out_queue = out_queue
         self.process_fn = process_fn
@@ -111,7 +136,7 @@ class PipelineWorker(threading.Thread):
     def stop(self) -> None:
         self._stop_event.set()
 
-    def run(self) -> None:
+    def _loop(self) -> None:
         while not self._stop_event.is_set():
             backlog = len(self.in_queue)
             if backlog == 0:
@@ -141,7 +166,7 @@ class PipelineWorker(threading.Thread):
                 self.out_queue.put_many(outputs)
 
 
-class StepPumpWorker(threading.Thread):
+class StepPumpWorker(_StageWorker):
     """Iteration-level pipeline stage (continuous batching).
 
     Instead of popping a whole batch and blocking until it drains, the
@@ -160,8 +185,9 @@ class StepPumpWorker(threading.Thread):
                  step_fn: Callable[[], Optional[List[Any]]],
                  on_policy_boundary: Optional[Callable[[], None]] = None,
                  policy_every: int = 8,
-                 idle_wait: float = 0.01):
-        super().__init__(name=name, daemon=True)
+                 idle_wait: float = 0.01,
+                 on_error: Optional[Callable[[BaseException], None]] = None):
+        super().__init__(name, on_error)
         self.in_queue = in_queue
         self.out_queue = out_queue
         self.capacity_fn = capacity_fn
@@ -178,7 +204,7 @@ class StepPumpWorker(threading.Thread):
     def stop(self) -> None:
         self._stop_event.set()
 
-    def run(self) -> None:
+    def _loop(self) -> None:
         while not self._stop_event.is_set():
             free = self.capacity_fn()
             items = self.in_queue.pop_batch(free) if free > 0 else []
@@ -225,6 +251,13 @@ class Pipeline:
         for w in self.workers:
             w.join(timeout=5.0)
 
+    def error(self) -> Optional[BaseException]:
+        """The first exception a worker thread died of, if any."""
+        for w in self.workers:
+            if w.error is not None:
+                return w.error
+        return None
+
     def idle_fraction(self, horizon: float) -> Dict[str, float]:
         return {w.name: 1.0 - min(w.stats.busy_seconds / horizon, 1.0)
                 for w in self.workers}
@@ -233,13 +266,16 @@ class Pipeline:
 def build_pipeline(retrieval_fn, generation_fn,
                    ret_scheduler: BacklogScheduler,
                    gen_scheduler: BacklogScheduler,
-                   on_ret_boundary=None, on_gen_boundary=None) -> Pipeline:
+                   on_ret_boundary=None, on_gen_boundary=None,
+                   on_error=None) -> Pipeline:
     rq = StageQueue("retrieval")
     cq = StageQueue("context")
     dq = StageQueue("done")
     rw = PipelineWorker("retrieval", rq, cq, retrieval_fn, ret_scheduler,
-                        on_batch_boundary=on_ret_boundary)
+                        on_batch_boundary=on_ret_boundary,
+                        on_error=on_error)
     gw = PipelineWorker("generation", cq, dq, generation_fn, gen_scheduler,
-                        on_batch_boundary=on_gen_boundary)
+                        on_batch_boundary=on_gen_boundary,
+                        on_error=on_error)
     return Pipeline(retrieval_queue=rq, context_queue=cq, done_queue=dq,
                     workers=[rw, gw])

@@ -98,8 +98,13 @@ class PlacementOptimizer:
                  kv_page_size: int = 16,
                  prefix_cache_frac: float = 0.25,
                  hot_fracs: Sequence[float] = (0.0, 0.125, 0.25, 0.5),
+                 weight_split: Optional[Tuple[float, float]] = None,
                  tracer=None, registry=None):
         self.cost = cost
+        # (w_gpu, w_cpu) the serving executor actually holds: its
+        # resident layers are fixed when it is built, so a solve that
+        # moved weights would price KV against memory the weights use
+        self.weight_split = weight_split
         self.tracer = tracer or NULL_TRACER
         self.registry = registry or NULL_REGISTRY
         self.avg_ctx = avg_ctx_len
@@ -424,24 +429,30 @@ class PlacementOptimizer:
         out = []
         p_max = self.cost.num_partitions
         nprobes = self._nprobe_grid()
+        if self.weight_split is None:
+            splits = [(wg, (1.0 - wg) * wc_frac)
+                      for wg in (0.0, 0.25, 0.5, 0.75, 1.0)
+                      for wc_frac in (1.0, 0.5, 0.0)]  # host share of rest
+        else:
+            splits = [self.weight_split]
         for pres in {0, p_max // 8, p_max // 4, p_max // 2,
                      3 * p_max // 4, p_max}:
-            for wg in (0.0, 0.25, 0.5, 0.75, 1.0):
-                for wc_frac in (1.0, 0.5, 0.0):     # host share of the rest
-                    for cg in (0.0, 0.5, 1.0):
-                        wc = (1.0 - wg) * wc_frac
-                        cand = Placement(
-                            w_gpu=wg, w_cpu=wc, c_gpu=cg,
-                            c_cpu=min(1.0 - cg, 1.0),
-                            resident_partitions=pres, gen_batch=gen_batch)
-                        cand = self.project(cand)
-                        if not self.feasible(cand):
-                            continue
-                        # nprobe is memory-neutral: feasibility is shared
-                        # across the whole probe-width column
-                        for nprobe in nprobes:
-                            out.append(dataclasses.replace(cand,
-                                                           nprobe=nprobe))
+            for wg, wc in splits:
+                for cg in (0.0, 0.5, 1.0):
+                    cand = Placement(
+                        w_gpu=wg, w_cpu=wc, c_gpu=cg,
+                        c_cpu=min(1.0 - cg, 1.0),
+                        resident_partitions=pres, gen_batch=gen_batch)
+                    cand = self.project(cand)
+                    if not self.feasible(cand) or (
+                            self.weight_split is not None
+                            and not math.isclose(cand.w_gpu, wg)):
+                        continue
+                    # nprobe is memory-neutral: feasibility is shared
+                    # across the whole probe-width column
+                    for nprobe in nprobes:
+                        out.append(dataclasses.replace(cand,
+                                                       nprobe=nprobe))
         return out
 
     def solve(self, gen_batch: int) -> Placement:

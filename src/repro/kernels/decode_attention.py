@@ -24,10 +24,11 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(q_ref, k_ref, v_ref, kvlen_ref, o_ref,
+def _kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref,
             m_scr, l_scr, acc_scr, *,
             scale: float, window: Optional[int], softcap: Optional[float],
             block_kv: int, nk: int):
+    b = pl.program_id(0)
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -36,7 +37,7 @@ def _kernel(q_ref, k_ref, v_ref, kvlen_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    kv_len = kvlen_ref[0]
+    kv_len = kvlen_ref[b]
     k_start = ik * block_kv
     needed = k_start < kv_len
     if window is not None:
@@ -102,22 +103,29 @@ def decode_attention_pallas(
         _kernel, scale=scale, window=window, softcap=softcap,
         block_kv=block_kv, nk=nk)
 
-    out = pl.pallas_call(
-        kernel,
+    # per-row kv_len rides scalar prefetch (SMEM), as in the paged kernel
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, kvh, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda b, kh, ik: (b, kh, 0, 0)),
-            pl.BlockSpec((1, 1, block_kv, d), lambda b, kh, ik: (b, kh, ik, 0)),
-            pl.BlockSpec((1, 1, block_kv, d), lambda b, kh, ik: (b, kh, ik, 0)),
-            pl.BlockSpec((1,), lambda b, kh, ik: (b,)),
+            pl.BlockSpec((1, 1, g, d), lambda b, kh, ik, kl: (b, kh, 0, 0)),
+            pl.BlockSpec((1, 1, block_kv, d),
+                         lambda b, kh, ik, kl: (b, kh, ik, 0)),
+            pl.BlockSpec((1, 1, block_kv, d),
+                         lambda b, kh, ik, kl: (b, kh, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda b, kh, ik: (b, kh, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, g, d),
+                               lambda b, kh, ik, kl: (b, kh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
         interpret=interpret,
-    )(qg, kt, vt, kv_len)
+    )(kv_len, qg, kt, vt)
     return out.reshape(b, h, d)

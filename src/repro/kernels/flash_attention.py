@@ -27,7 +27,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(q_ref, k_ref, v_ref, kvlen_ref, o_ref,
+def _kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref,
             m_scr, l_scr, acc_scr, *,
             scale: float, causal: bool, window: Optional[int],
             softcap: Optional[float], block_q: int, block_kv: int,
@@ -63,7 +63,7 @@ def _kernel(q_ref, k_ref, v_ref, kvlen_ref, o_ref,
             s = softcap * jnp.tanh(s / softcap)
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = k_pos < kvlen_ref[0]
+        mask = k_pos < kvlen_ref[b]
         if causal:
             mask &= k_pos <= q_pos
         if window is not None:
@@ -122,25 +122,31 @@ def flash_attention_pallas(
         softcap=softcap, block_q=block_q, block_kv=block_kv, nk=nk,
         q_offset=q_offset)
 
-    out = pl.pallas_call(
-        kernel,
+    # per-row kv_len rides scalar prefetch (SMEM): a rank-1 (1,) VMEM
+    # block over (B,) is neither the full dim nor lane-aligned for B > 1
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, h, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_q, d),
+                         lambda b, h, iq, ik, kl: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, block_kv, d),
-                         lambda b, h, iq, ik, g=group: (b, h // g, ik, 0)),
+                         lambda b, h, iq, ik, kl, g=group: (b, h // g, ik, 0)),
             pl.BlockSpec((1, 1, block_kv, dv),
-                         lambda b, h, iq, ik, g=group: (b, h // g, ik, 0)),
-            pl.BlockSpec((1,), lambda b, h, iq, ik: (b,)),
+                         lambda b, h, iq, ik, kl, g=group: (b, h // g, ik, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, dv),
-                               lambda b, h, iq, ik: (b, h, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
+                               lambda b, h, iq, ik, kl: (b, h, iq, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
         interpret=interpret,
-    )(qt, kt, vt, kv_len)
+    )(kv_len, qt, kt, vt)
     return out.transpose(0, 2, 1, 3)   # (B, Sq, H, D)

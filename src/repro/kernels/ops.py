@@ -11,11 +11,21 @@ Every op has three tiers:
   * the naive reference in ``ref.py`` (the oracle).
 
 ``impl=None`` auto-selects: pallas on TPU, the jnp-blocked tier elsewhere.
+On TPU the only non-Pallas choice is explicit: ``flash_attention`` with a
+per-row ``q_offset`` array (chunked prefill) routes to ``kv_scan``, which
+masks per batch row.  Pallas kernels run in interpret mode only off TPU.
+
+Every dispatch is counted in ``DISPATCH_COUNTS`` under ``(op, impl)``.
+Dispatch runs while a caller traces, so under ``jit`` each compiled
+program counts once; ``dispatch_counts()`` is how a run reports which
+implementation each op took.
 """
 from __future__ import annotations
 
+import collections
 import functools
-from typing import Optional, Tuple
+import threading
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,8 +35,32 @@ from repro.kernels import ref
 NEG_INF = -1e30
 
 
+DISPATCH_COUNTS: "collections.Counter[Tuple[str, str]]" = \
+    collections.Counter()
+_DISPATCH_LOCK = threading.Lock()
+
+
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _dispatch(op: str, impl: Optional[str], tpu: str, other: str) -> str:
+    """Resolve ``impl`` (``None``: ``tpu`` on TPU, else ``other``) and
+    count the choice."""
+    if impl is None:
+        impl = tpu if _on_tpu() else other
+    with _DISPATCH_LOCK:
+        DISPATCH_COUNTS[(op, impl)] += 1
+    return impl
+
+
+def dispatch_counts() -> Dict[str, Dict[str, int]]:
+    """``{op: {impl: traces}}`` of every dispatch so far."""
+    out: Dict[str, Dict[str, int]] = {}
+    with _DISPATCH_LOCK:
+        for (op, impl), n in sorted(DISPATCH_COUNTS.items()):
+            out.setdefault(op, {})[impl] = n
+    return out
 
 
 def _softcap(x, cap):
@@ -52,17 +86,15 @@ def flash_attention(
     block_q: int = 256,
     block_kv: int = 256,
 ) -> jnp.ndarray:
-    if impl is None:
-        if _on_tpu() and isinstance(q_offset, int):
-            impl = "pallas"
-        elif (q.shape[1] % 256 == 0 and k.shape[1] % 256 == 0
-              and isinstance(q_offset, int)):
-            impl = "flash_vjp"       # memory-efficient fwd AND bwd
-        else:
-            # per-row q_offset arrays (chunked prefill) route here: the
-            # scan path masks per batch row, which the TPU kernel and
-            # flash_vjp do not support
-            impl = "kv_scan"
+    if impl is None and not isinstance(q_offset, int):
+        # per-row q_offset arrays (chunked prefill) route here on every
+        # backend: the scan path masks per batch row, which the TPU
+        # kernel and flash_vjp do not support
+        impl = "kv_scan"
+    aligned = q.shape[1] % 256 == 0 and k.shape[1] % 256 == 0
+    # off TPU, aligned shapes take flash_vjp (memory-efficient fwd AND bwd)
+    impl = _dispatch("flash_attention", impl, "pallas",
+                     "flash_vjp" if aligned else "kv_scan")
     if impl == "naive":
         return ref.attention_reference(
             q, k, v, causal=causal, window=window, softcap=softcap,
@@ -257,8 +289,7 @@ def decode_attention(
     impl: Optional[str] = None,
     block_kv: int = 512,
 ) -> jnp.ndarray:
-    if impl is None:
-        impl = "pallas" if _on_tpu() else "einsum"
+    impl = _dispatch("decode_attention", impl, "pallas", "einsum")
     if impl == "naive":
         return ref.decode_attention_reference(
             q, k_cache, v_cache, kv_len, window=window, softcap=softcap,
@@ -345,8 +376,7 @@ def paged_decode_attention(
     gather/naive tiers dequantize at gather time), so the cross-backend
     identity contract survives quantization.
     """
-    if impl is None:
-        impl = "pallas" if _on_tpu() else "gather"
+    impl = _dispatch("paged_decode_attention", impl, "pallas", "gather")
     if impl == "naive":
         return ref.paged_decode_attention_reference(
             q, k_pool, v_pool, block_tab, kv_len, kv_span=kv_span,
@@ -381,8 +411,7 @@ def retrieval_topk(
     block_n: int = 4096,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Exact top-k by inner product. Returns (scores (Q,k), indices (Q,k))."""
-    if impl is None:
-        impl = "pallas" if _on_tpu() else "blocked"
+    impl = _dispatch("retrieval_topk", impl, "pallas", "blocked")
     if impl == "naive":
         return ref.topk_reference(queries, database, k)
     if impl == "pallas":
@@ -444,8 +473,7 @@ def retrieval_topk_merge(
     their ids to the ``-1`` sentinel, so when fewer than ``k`` real
     candidates exist the output tail is ``(NEG_INF, -1)``, never a
     phantom id (all three backends + the ref oracle agree)."""
-    if impl is None:
-        impl = "pallas" if _on_tpu() else "blocked"
+    impl = _dispatch("retrieval_topk_merge", impl, "pallas", "blocked")
     if impl == "naive":
         return ref.topk_merge_reference(part_scores, part_ids, mask, k)
     if impl == "pallas":
@@ -488,8 +516,7 @@ def _topk_merge_blocked(part_scores, part_ids, mask, k):
 
 def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6,
             *, impl: Optional[str] = None) -> jnp.ndarray:
-    if impl is None:
-        impl = "pallas" if _on_tpu() else "jnp"
+    impl = _dispatch("rmsnorm", impl, "pallas", "jnp")
     if impl == "pallas":
         from repro.kernels import rmsnorm as rk
         return rk.rmsnorm_pallas(x, w, eps, interpret=not _on_tpu())

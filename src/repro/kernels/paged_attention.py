@@ -23,10 +23,14 @@ using 2 of 8 table entries issues 2 distinct page fetches per head, not
 ``pl.when`` gates its compute.)
 
 Quantized pools (``k_scale``/``v_scale`` given): KV pages are int8 and a
-``(P, KV)`` fp32 per-page-per-head scale array rides the scalar-prefetch
-machinery next to the block table; the kernel dequantizes each gathered
-page inside the grid (``int8 page * scale[tab[b, ik], kh]``) before the
-fp32 online-softmax accumulation, so quantization never touches the
+``(P, KV)`` fp32 per-page-per-head scale array is viewed as ``(P, 1, KV)``
+and streamed into VMEM one ``(1, 1, KV)`` block per grid step, routed by
+the same clamped block-table lookup as the page itself.  (Scalar prefetch
+would put the whole array in SMEM, which is 1 MiB and pads each row to
+128 words, so pools beyond about 2k pages could not compile.)  The kernel
+picks head ``kh``'s lane and dequantizes each gathered page inside the
+grid (``int8 page * scale[tab[b, ik], kh]``) before the fp32
+online-softmax accumulation, so quantization never touches the
 accumulation precision.
 """
 from __future__ import annotations
@@ -73,11 +77,16 @@ def _kernel(tab_ref, kvlen_ref, *refs,
         k = k_ref[0, 0].astype(jnp.float32)                  # (page, D)
         v = v_ref[0, 0].astype(jnp.float32)
         if quant:
-            # per-page-per-head dequant: the scales sit in SMEM via
-            # scalar prefetch, indexed by the same block-table entry
-            # that routed this page's DMA
-            k = k * ks_ref[tab_ref[b, ik], kh]
-            v = v * vs_ref[tab_ref[b, ik], kh]
+            # per-page-per-head dequant: the (1, KV) scale block was
+            # routed by the same block-table entry as this page's DMA;
+            # head kh's lane is selected by a masked lane reduction
+            def head_scale(ref):
+                row = ref[0]                                 # (1, KV)
+                lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+                return jnp.sum(jnp.where(lane == kh, row, 0.0), axis=1,
+                               keepdims=True)                # (1, 1)
+            k = k * head_scale(ks_ref)
+            v = v * head_scale(vs_ref)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if softcap is not None:
@@ -115,9 +124,19 @@ def _kv_index_map(page: int):
     resolve to the same (already resident) page, which the Pallas
     pipeline recognizes and skips re-fetching.
     """
-    def index_map(b, kh, ik, tab, kl, *_):
+    def index_map(b, kh, ik, tab, kl):
         last = jnp.maximum((kl[b] + page - 1) // page - 1, 0)
         return (tab[b, jnp.minimum(ik, last)], kh, 0, 0)
+    return index_map
+
+
+def _scale_index_map(page: int):
+    """The page lookup of :func:`_kv_index_map` for ``(P, 1, KV)`` scale
+    blocks: every head of a page shares one block."""
+    kv_map = _kv_index_map(page)
+
+    def index_map(b, kh, ik, tab, kl):
+        return (kv_map(b, kh, ik, tab, kl)[0], 0, 0)
     return index_map
 
 
@@ -154,23 +173,24 @@ def paged_decode_attention_pallas(
         page=page, nk=nmax, quant=quant)
 
     kv_map = _kv_index_map(page)
-    n_prefetch = 4 if quant else 2
-    operands = [block_tab, kv_len]
+    in_specs = [
+        pl.BlockSpec((1, 1, g, d), lambda b, kh, ik, tab, kl: (b, kh, 0, 0)),
+        pl.BlockSpec((1, 1, page, d), kv_map),
+        pl.BlockSpec((1, 1, page, d), kv_map),
+    ]
+    operands = [block_tab, kv_len, qg, kt, vt]
     if quant:
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        scale_spec = pl.BlockSpec((1, 1, kvh), _scale_index_map(page))
+        in_specs = [scale_spec, scale_spec] + in_specs
+        operands[2:2] = [k_scale.astype(jnp.float32).reshape(p_pages, 1, kvh),
+                         v_scale.astype(jnp.float32).reshape(p_pages, 1, kvh)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,  # tab, kv_len[, k_scale, v_scale]
+        num_scalar_prefetch=2,           # block table, kv_len
         grid=(b, kvh, nmax),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d),
-                         lambda b, kh, ik, tab, kl, *_: (b, kh, 0, 0)),
-            pl.BlockSpec((1, 1, page, d), kv_map),
-            pl.BlockSpec((1, 1, page, d), kv_map),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda b, kh, ik, tab, kl, *_: (b, kh, 0, 0)),
+                               lambda b, kh, ik, tab, kl: (b, kh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g,), jnp.float32),
@@ -183,5 +203,5 @@ def paged_decode_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
         interpret=interpret,
-    )(*operands, qg, kt, vt)
+    )(*operands)
     return out.reshape(b, h, d)

@@ -11,6 +11,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# fp32 bytes of one row tile: the input and output tiles are
+# double-buffered and the fp32 temporaries share the 16 MiB scoped VMEM
+_TILE_BYTES = 2 ** 20
+
 
 def _kernel(x_ref, w_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -25,7 +29,7 @@ def rmsnorm_pallas(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6,
     d = shape[-1]
     x2 = x.reshape(-1, d)
     r = x2.shape[0]
-    block_r = min(block_r, r)
+    block_r = min(block_r, max(8, _TILE_BYTES // (4 * d) // 8 * 8), r)
     pad = -r % block_r
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))

@@ -11,16 +11,10 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-
-try:                                  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                   # pinned jax 0.4.x: implicit Auto axes
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(dev, axes):
-    if AxisType is None:
-        return jax.sharding.Mesh(dev, axes)
     return jax.sharding.Mesh(dev, axes,
                              axis_types=(AxisType.Auto,) * len(axes))
 

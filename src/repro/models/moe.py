@@ -23,7 +23,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models import layers
-from repro.sharding.specs import MeshContext, shard_map_compat
+from repro.sharding.specs import MeshContext
 
 # TP-MoE psum precision: f32 by default; set to jnp.bfloat16 to halve the
 # per-layer all-reduce bytes (hillclimb lever, EXPERIMENTS.md section Perf;
@@ -211,7 +211,7 @@ def moe_forward(
             (load / (t * m.top_k)) * (imp / t)) * m.aux_loss_coef
         return out.reshape(bl, sl, d).astype(x_.dtype), aux
 
-    return shard_map_compat(
+    return jax.shard_map(
         fn, mesh=ctx.mesh, in_specs=(wspec, tok_spec),
         out_specs=(tok_spec, P()), check_vma=False)(p, x)
 
@@ -294,6 +294,6 @@ def moe_forward_ep(
             (load / (t * m.top_k)) * (imp / t)) * m.aux_loss_coef
         return out.reshape(bl, sl, d).astype(x_.dtype), aux
 
-    return shard_map_compat(
+    return jax.shard_map(
         fn, mesh=ctx.mesh, in_specs=(wspec, tok_spec),
         out_specs=(tok_spec, P()), check_vma=False)(p, x)

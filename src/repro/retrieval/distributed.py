@@ -58,7 +58,7 @@ from repro.obs.trace import NULL_TRACER
 from repro.retrieval.cache import HotPartitionSet
 from repro.retrieval.streamer import PartitionStreamer
 from repro.retrieval.vectorstore import SearchStats, VectorStore
-from repro.sharding.specs import MeshContext, shard_map_compat
+from repro.sharding.specs import MeshContext
 
 NEG_INF = -1e30
 
@@ -128,7 +128,7 @@ def distributed_topk(
         top_i = jnp.take_along_axis(i_cat, pos, axis=1)
         return top_s, top_i
 
-    return shard_map_compat(
+    return jax.shard_map(
         fn, mesh=ctx.mesh,
         in_specs=(P(None, None), P(axes, None)),
         out_specs=(P(None, None), P(None, None)),
@@ -214,7 +214,7 @@ def sharded_topk_merge(
         top_s, pos = jax.lax.top_k(s_cat, k)
         return top_s, jnp.take_along_axis(i_cat, pos, axis=1)
 
-    return shard_map_compat(
+    return jax.shard_map(
         fn, mesh=ctx.mesh,
         in_specs=(P(axes, None, None), P(axes, None, None)),
         out_specs=(P(None, None), P(None, None)),

@@ -154,7 +154,8 @@ class RagdollEngine:
                           StageQueue("done"))
             rw = PipelineWorker("retrieval", rq, cq, self._retrieve_batch,
                                 ret_scheduler,
-                                on_batch_boundary=self._ret_boundary)
+                                on_batch_boundary=self._ret_boundary,
+                                on_error=self._worker_failed)
             # the request scheduler owns admission / preemption / resume
             # (priority classes, partial-slot swap, swap/decode overlap
             # fencing); the pump wires its capacity + admit hooks
@@ -167,7 +168,8 @@ class RagdollEngine:
                 admit_fn=self.scheduler.admit,
                 step_fn=self._generate_step,
                 on_policy_boundary=self._gen_boundary,
-                policy_every=policy_every)
+                policy_every=policy_every,
+                on_error=self._worker_failed)
             self.pipeline = Pipeline(retrieval_queue=rq, context_queue=cq,
                                      done_queue=dq, workers=[rw, gw])
         else:
@@ -176,7 +178,8 @@ class RagdollEngine:
                 self._retrieve_batch, self._generate_batch,
                 ret_scheduler, gen_scheduler,
                 on_ret_boundary=self._ret_boundary,
-                on_gen_boundary=self._gen_boundary)
+                on_gen_boundary=self._gen_boundary,
+                on_error=self._worker_failed)
         self.gen_scheduler = gen_scheduler
 
     # ------------------------------------------------------------- stages
@@ -417,6 +420,11 @@ class RagdollEngine:
                 float(len(self.completed)))
         return reg.snapshot()
 
+    def _worker_failed(self, err: BaseException) -> None:
+        """A stage thread died: wake ``drain`` so it re-raises now."""
+        with self._done_cv:
+            self._done_cv.notify_all()
+
     # ------------------------------------------------------------- public
     def pump_once(self) -> int:
         """One synchronous generation-pump iteration: capacity probe →
@@ -467,10 +475,14 @@ class RagdollEngine:
         """Block until ``n`` requests have completed (condition-variable
         wakeup, no polling).  Raises :class:`TimeoutError` — naming the
         in-flight rids and the scheduler's state snapshot — instead of
-        silently returning fewer than ``n``."""
+        silently returning fewer than ``n``.  If a stage thread died,
+        re-raises its exception as soon as it is recorded."""
         deadline = time.monotonic() + timeout
         with self._done_cv:
             while len(self.completed) < n:
+                err = self.pipeline.error()
+                if err is not None:
+                    raise err
                 left = deadline - time.monotonic()
                 if left <= 0 or not self._done_cv.wait(timeout=left):
                     if len(self.completed) >= n:
@@ -502,6 +514,7 @@ class SerialRAGEngine:
         # arrivals) and the completion wakeup (drain waits for results)
         self._cv = threading.Condition(self._lock)
         self._stop = threading.Event()
+        self._error: Optional[BaseException] = None
         self._thread = threading.Thread(target=self._run, daemon=True)
 
     def start(self) -> None:
@@ -519,6 +532,14 @@ class SerialRAGEngine:
             self._cv.notify_all()
 
     def _run(self) -> None:
+        try:
+            self._serve()
+        except Exception as e:     # thread boundary: record and report
+            with self._cv:
+                self._error = e
+                self._cv.notify_all()
+
+    def _serve(self) -> None:
         while not self._stop.is_set():
             with self._cv:
                 while not self.queue and not self._stop.is_set():
@@ -548,10 +569,13 @@ class SerialRAGEngine:
     def drain(self, n: int, timeout: float = 120.0) -> List[Request]:
         """Block until ``n`` requests have completed.  Raises
         :class:`TimeoutError` naming the still-queued rids instead of
-        silently returning fewer than ``n``."""
+        silently returning fewer than ``n``.  If the worker thread died,
+        re-raises its exception as soon as it is recorded."""
         deadline = time.monotonic() + timeout
         with self._cv:
             while len(self.completed) < n:
+                if self._error is not None:
+                    raise self._error
                 left = deadline - time.monotonic()
                 if left <= 0 or not self._cv.wait(timeout=left):
                     if len(self.completed) >= n:

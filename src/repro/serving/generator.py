@@ -95,14 +95,21 @@ class _GeneratorBase:
 
     def __init__(self, cfg: ModelConfig, params, gen_cfg: GeneratorConfig,
                  streamed: bool = False,
-                 policy: Optional[PrefetchPolicy] = None):
+                 policy: Optional[PrefetchPolicy] = None,
+                 resident_layers: int = 0,
+                 free_bytes: float = float("inf")):
+        """``resident_layers`` and ``free_bytes`` configure the streamed
+        executor: how many leading layers stay in device memory, and the
+        device bytes its prefetch queue may fill."""
         self.cfg = cfg
         self.gen_cfg = gen_cfg
         self.tok = HashTokenizer(cfg.vocab_size)
         self.streamed = streamed
         if streamed:
             self.exec = StreamedExecutor(cfg, params,
-                                         policy or PrefetchPolicy())
+                                         policy or PrefetchPolicy(),
+                                         resident_layers=resident_layers,
+                                         free_bytes=free_bytes)
             self.model = None
             self.params = None
         else:
@@ -388,9 +395,12 @@ class ContinuousGenerator(_GeneratorBase):
                  prefix_page_budget: Optional[int] = None,
                  kv_format: Optional[str] = None,
                  overlap_swap: bool = False,
+                 resident_layers: int = 0,
+                 free_bytes: float = float("inf"),
                  tracer=None, registry=None):
         super().__init__(cfg, params, gen_cfg, streamed=streamed,
-                         policy=policy)
+                         policy=policy, resident_layers=resident_layers,
+                         free_bytes=free_bytes)
         self.tracer = tracer or NULL_TRACER
         self.registry = registry or NULL_REGISTRY
         # slot -> the joining request's trace-id scope, so decode/swap
@@ -416,6 +426,8 @@ class ContinuousGenerator(_GeneratorBase):
         self.prefill_tokens = 0       # prompt tokens actually prefilled
         self.prefix_hit_tokens = 0    # prompt tokens served from the cache
         self.cow_copies = 0
+        # logits batches (prefill or decode) holding a NaN or inf
+        self.nonfinite_batches = 0
         self._prefilling: Dict[int, _ChunkJob] = {}
         self._parked: Dict[Any, _Parked] = {}
         # slots whose async H2D swap-in is outstanding: leased, but
@@ -553,6 +565,15 @@ class ContinuousGenerator(_GeneratorBase):
                     self.cache["prefix"], row_cache["prefix"])
             self.cache = new
 
+    def _greedy(self, logits) -> np.ndarray:
+        """Greedy tokens of a logits batch, read back with one transfer
+        that also counts a batch holding a non-finite logit."""
+        nxt, finite = jax.device_get(
+            (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+             jnp.isfinite(logits).all()))
+        self.nonfinite_batches += int(not finite)
+        return nxt
+
     def _emit(self, ref: SlotRef, token: int) -> None:
         """Append one token; finish + free the slot on EOS / budget end."""
         st = self.table.advance(ref, token)
@@ -647,7 +668,7 @@ class ContinuousGenerator(_GeneratorBase):
                     logits, self.cache = self._chunk_paged(
                         self.params, chunk, self.cache, off, bt)
             self._prefix_insert(ref.index, ptoks)
-            self._emit(ref, int(np.asarray(jnp.argmax(logits, -1))[0]))
+            self._emit(ref, int(self._greedy(logits)[0]))
             return ref
         with self.tracer.span("prefill", slot=ref.index, tokens=g.ctx_len):
             toks = jnp.asarray(ptoks[None])
@@ -669,7 +690,7 @@ class ContinuousGenerator(_GeneratorBase):
                     self._scatter_row(row, ref.index)
         if self.paged:
             self._prefix_insert(ref.index, ptoks)
-        self._emit(ref, int(np.asarray(jnp.argmax(logits, axis=-1))[0]))
+        self._emit(ref, int(self._greedy(logits)[0]))
         return ref
 
     # --------------------------------------------------- prefix sharing
@@ -804,8 +825,7 @@ class ContinuousGenerator(_GeneratorBase):
                         if job.offset >= g.ctx_len:
                             finished.append(
                                 (slot,
-                                 int(np.asarray(jnp.argmax(logits,
-                                                           -1))[0])))
+                                 int(self._greedy(logits)[0])))
                     continue
                 n = len(members)
                 padn = 1 << (n - 1).bit_length()
@@ -824,7 +844,7 @@ class ContinuousGenerator(_GeneratorBase):
                     jnp.asarray(rows), self.caches,
                     jnp.asarray(offs, jnp.int32), block_tab=bt,
                     kv_span=g.ctx_len)
-                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+                nxt = self._greedy(logits)
                 for i, (slot, job) in enumerate(members):
                     job.offset += c
                     if job.offset >= g.ctx_len:
@@ -898,8 +918,7 @@ class ContinuousGenerator(_GeneratorBase):
                 else:
                     logits, self.cache = self._decode(self.params, cur,
                                                       self.cache, pos)
-            nxt = np.asarray(jnp.argmax(logits,
-                                        axis=-1)).astype(np.int32)
+            nxt = self._greedy(logits)
         if (self.paged and self.registry.enabled
                 and self.kv.kv_format == "int8"):
             # dequant traffic: this step's fused kernel read every live

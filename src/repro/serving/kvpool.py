@@ -414,6 +414,10 @@ class PagePool:
         stays parked host-side).  ``unpark`` is the partial-residency
         generalization.
         """
+        # a full swap_out leaves no retained tail under ``key``, so any
+        # table held under it is a live one
+        if key in self._tables:
+            raise ValueError(f"slot {key!r} already holds pages")
         return self.unpark(key, key, blocks, reserve)
 
     # ------------------------------------------------------------- resize

@@ -19,17 +19,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` (jax >= 0.6) / ``jax.experimental.shard_map``
-    (pinned 0.4.x, where the replication check is named ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check_vma)
-
-
 @dataclass(frozen=True)
 class MeshContext:
     mesh: Mesh

@@ -86,3 +86,74 @@ def test_workers_observe_timings():
         assert w.stats.batches >= 1
         assert w.stats.items == 16
         assert len(w.scheduler.samples) > 0
+
+
+class _Boom(RuntimeError):
+    pass
+
+
+def test_worker_error_is_recorded_not_swallowed():
+    """A stage function that raises ends its worker thread with the
+    error recorded on the worker and the pipeline (not a silent death)."""
+    def gen_fn(items):
+        raise _Boom("generation failed")
+
+    seen = []
+    pipe = build_pipeline(lambda x: x, gen_fn, _sched(), _sched(),
+                          on_error=seen.append)
+    pipe.start()
+    pipe.retrieval_queue.put(1)
+    t0 = time.time()
+    while pipe.error() is None and time.time() - t0 < 30:
+        time.sleep(0.002)
+    pipe.stop()
+    assert isinstance(pipe.error(), _Boom)
+    assert len(seen) == 1 and seen[0] is pipe.error()
+
+
+@pytest.mark.parametrize("engine", ["ragdoll", "serial"])
+def test_drain_reraises_failed_step_at_once(engine):
+    """``drain`` re-raises a worker's exception as soon as the thread
+    dies, instead of waiting out its timeout with a bare TimeoutError."""
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.models.model import Model
+    from repro.retrieval import HashEmbedder, VectorStore
+    from repro.serving.engine import RagdollEngine, SerialRAGEngine
+    from repro.serving.generator import (ContinuousGenerator, Generator,
+                                         GeneratorConfig)
+    from repro.serving.request import Request
+
+    cfg = get_config("llama3-8b").reduced(num_layers=2)
+    params = Model(cfg, remat=False).init(jax.random.PRNGKey(0), jnp.float32)
+    gcfg = GeneratorConfig(ctx_len=16, max_new_tokens=2)
+    emb = HashEmbedder(dim=16)
+    texts = [f"doc {i}" for i in range(24)]
+
+    def boom(*_a, **_k):
+        raise _Boom("decode step failed")
+
+    with tempfile.TemporaryDirectory() as root:
+        store = VectorStore.build(texts, emb, num_partitions=2, root=root)
+        if engine == "ragdoll":
+            gen = ContinuousGenerator(cfg, params, gcfg, num_slots=2,
+                                      paged=True)
+            gen.step = boom                     # the injected step_fn fault
+            eng = RagdollEngine(store, emb, gen, BacklogScheduler(4),
+                                BacklogScheduler(2))
+        else:
+            gen = Generator(cfg, params, gcfg)
+            gen.generate = boom
+            eng = SerialRAGEngine(store, emb, gen, batch_size=2)
+        eng.start()
+        eng.submit(Request(rid=0, query="doc 3", arrival=time.perf_counter()))
+        t0 = time.monotonic()
+        with pytest.raises(_Boom):
+            eng.drain(1, timeout=120)
+        waited = time.monotonic() - t0
+        eng.stop()
+    assert waited < 60, waited

@@ -60,13 +60,14 @@ LOGIT_BOUND = 0.025
 # model's logits are tie-dense (top-2 spacing of ~500 near-iid values),
 # so arbitrary prompts WILL flip an argmax under ~1e-2 quantization
 # noise somewhere in a 34-step horizon.  These four prompts were
-# selected so each fp32 greedy trajectory keeps its top-1/top-2 gap
-# above LOGIT_BOUND at every step (asserted below, not just assumed).
+# selected (on jax 0.9.0's CPU backend) so each fp32 greedy trajectory
+# keeps its top-1/top-2 gap above LOGIT_BOUND at every step (asserted
+# below, not just assumed).
 E2E_PROMPTS = [
-    "seed8 request 3 about retrieval topic 59",
-    "seed5 request 2 about retrieval topic 37",
-    "seed8 request 0 about retrieval topic 56",
-    "seed9 request 0 about retrieval topic 63",
+    "seed9 request 0 about retrieval topic 51",
+    "seed8 request 0 about retrieval topic 60",
+    "seed5 request 1 about retrieval topic 15",
+    "seed7 request 3 about retrieval topic 24",
 ]
 E2E_CTX, E2E_HORIZON = 30, 34          # horizon >= 32 tokens
 
@@ -354,7 +355,7 @@ else:
 
 
 @_scales_property
-def test_scales_survive_preempt_resume_and_cow(seed=0, ops_seq=("swap",)):
+def test_scales_survive_preempt_resume_and_cow(seed, ops_seq):
     """Property: whatever interleaving of preempt/resume round trips,
     CoW detaches, and further quantized appends a slot experiences, its
     logical pages (int8 payload + per-page scale rows) always read back
