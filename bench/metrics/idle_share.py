@@ -1,0 +1,8 @@
+"""``idle_share``, in cells with every layer resident (see ``lib/layer.py``)."""
+from lib import layer
+
+
+def read(ctx):
+    if ctx["streamed_bytes"]:
+        return None
+    return layer.idle_share(ctx)

@@ -1,0 +1,8 @@
+"""``idle_share``, in cells with layers streamed from host memory (see ``lib/layer.py``)."""
+from lib import layer
+
+
+def read(ctx):
+    if not ctx["streamed_bytes"]:
+        return None
+    return layer.idle_share(ctx)
