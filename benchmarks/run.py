@@ -14,8 +14,6 @@ MODULES = [
     "fig9_policy_trace",
     "fig10_topk_sweep",
     "fig11_ondisk_index",
-    "kernel_micro",
-    "roofline_report",
 ]
 
 

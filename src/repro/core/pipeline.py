@@ -20,6 +20,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Deque, Dict, List, Optional
 
 from repro.core.scheduler import BacklogScheduler
+from repro.obs.trace import NULL_SPAN, NULL_TRACER
 
 
 class StageQueue:
@@ -176,6 +177,13 @@ class StepPumpWorker(_StageWorker):
     lazy-reconfiguration hook ``on_policy_boundary`` runs every
     ``policy_every`` steps — the paper's dynamic batch policy acting
     *within* a generation rather than only between whole batches.
+
+    ``tracer_fn()`` returns the tracer to record into, read on every
+    iteration (an engine may bind one late).  An iteration that did work
+    records a ``pump.step`` span over capacity probe, pop, admit and
+    step; the sleep while no slot is live and nothing is queued records
+    ``pump.wait``.  The rest of the thread's time is policy boundaries
+    and loop overhead.
     """
 
     def __init__(self, name: str, in_queue: StageQueue,
@@ -186,10 +194,12 @@ class StepPumpWorker(_StageWorker):
                  on_policy_boundary: Optional[Callable[[], None]] = None,
                  policy_every: int = 8,
                  idle_wait: float = 0.01,
-                 on_error: Optional[Callable[[BaseException], None]] = None):
+                 on_error: Optional[Callable[[BaseException], None]] = None,
+                 tracer_fn: Callable[[], Any] = lambda: NULL_TRACER):
         super().__init__(name, on_error)
         self.in_queue = in_queue
         self.out_queue = out_queue
+        self.tracer_fn = tracer_fn
         self.capacity_fn = capacity_fn
         self.admit_fn = admit_fn
         self.step_fn = step_fn
@@ -206,6 +216,8 @@ class StepPumpWorker(_StageWorker):
 
     def _loop(self) -> None:
         while not self._stop_event.is_set():
+            tracer = self.tracer_fn()
+            t_in = time.perf_counter() if tracer.enabled else 0.0
             free = self.capacity_fn()
             items = self.in_queue.pop_batch(free) if free > 0 else []
             t0 = time.perf_counter()
@@ -213,10 +225,15 @@ class StepPumpWorker(_StageWorker):
                 if items:
                     self.admit_fn(items)
                 outputs = self.step_fn()
-            dt = time.perf_counter() - t0
+            t1 = time.perf_counter()
+            dt = t1 - t0
             if outputs is None and not items:   # no live slots: sleep
-                self.in_queue.wait(self.idle_wait)
+                with (tracer.interval("pump.wait") if tracer.enabled
+                      else NULL_SPAN):
+                    self.in_queue.wait(self.idle_wait)
                 continue
+            if tracer.enabled:
+                tracer.complete("pump.step", t_in, t1)
             self._steps += 1
             if (self.on_policy_boundary is not None
                     and self._steps % self.policy_every == 0):

@@ -25,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import transformer
+from repro.obs.trace import NULL_SPAN, NULL_TRACER
 
 
 @dataclass
@@ -150,6 +151,11 @@ class StreamedExecutor:
     ``params`` is either the stacked pytree of ``Model.init`` or the
     per-layer form of :func:`init_layered_params` (a ``"layers"`` list);
     either way, streamed layers are moved to pinned host memory here.
+
+    With a ``tracer`` bound, each step records the host side of its eager
+    ends: ``step.head`` (the embedding), ``step.tail`` (final norm and
+    unembedding) and ``stream.wait`` (each wait that bounds the copy
+    queue).
     """
 
     def __init__(self, cfg: ModelConfig, params, policy: PrefetchPolicy,
@@ -157,6 +163,7 @@ class StreamedExecutor:
                  free_bytes: float = float("inf")):
         self.cfg = cfg
         self.policy = policy
+        self.tracer = NULL_TRACER
         self.device = device or jax.devices()[0]
         self.free_bytes = free_bytes
         self._on_dev = SingleDeviceSharding(self.device)
@@ -246,18 +253,30 @@ class StreamedExecutor:
                 # the device stays busy while layer i-1 finishes and frees
                 # its streamed weights: at most depth + 1 streamed layers
                 # are then in device memory
-                jax.block_until_ready(x_prev)
+                with self._span("stream.wait", layer=i):
+                    jax.block_until_ready(x_prev)
             ensure(i + depth)           # keep the queue full
         return x, (new_caches if caches is not None else None)
 
     # ------------------------------------------------------------- public
+    def _span(self, name: str, **attrs):
+        return (self.tracer.interval(name, **attrs) if self.tracer.enabled
+                else NULL_SPAN)
+
+    def _head(self, inputs):
+        with self._span("step.head"):
+            return transformer._embed_inputs(self.top, self.cfg, inputs)
+
+    def _tail(self, x):
+        """Final norm and unembedding of ``x`` (B, 1, D)."""
+        with self._span("step.tail"):
+            x = L.rms_norm(x, self.top["final_norm"], self.cfg.norm_eps)
+            return transformer.unembed(self.top, self.cfg, x, None)[:, 0]
+
     def prefill(self, inputs, caches: List[dict], enc_embeds=None):
-        cfg = self.cfg
-        x = transformer._embed_inputs(self.top, cfg, inputs)
+        x = self._head(inputs)
         x, new_caches = self._stream(x, caches, None, "prefill")
-        x = L.rms_norm(x[:, -1:], self.top["final_norm"], cfg.norm_eps)
-        logits = transformer.unembed(self.top, cfg, x, None)[:, 0]
-        return logits, new_caches
+        return self._tail(x[:, -1:]), new_caches
 
     def decode(self, inputs, caches: List[dict], pos, slot_mask=None,
                block_tab=None, kv_span=None):
@@ -281,12 +300,10 @@ class StreamedExecutor:
         if slot_mask is not None \
                 and not np.asarray(slot_mask).astype(bool).any():
             return jnp.zeros((inputs.shape[0], cfg.vocab_size)), caches
-        x = transformer._embed_inputs(self.top, cfg, inputs)
+        x = self._head(inputs)
         x, new_caches = self._stream(x, caches, pos, "decode",
                                      block_tab=block_tab, kv_span=kv_span)
-        x = L.rms_norm(x, self.top["final_norm"], cfg.norm_eps)
-        logits = transformer.unembed(self.top, cfg, x, None)[:, 0]
-        return logits, new_caches
+        return self._tail(x), new_caches
 
     def prefill_chunk(self, inputs, caches: List[dict], offset,
                       block_tab=None, kv_span=None):
@@ -297,13 +314,10 @@ class StreamedExecutor:
         attention spans the cache written by earlier chunks.  Returns
         the chunk's last-position logits and the updated caches.
         """
-        cfg = self.cfg
-        x = transformer._embed_inputs(self.top, cfg, inputs)
+        x = self._head(inputs)
         x, new_caches = self._stream(x, caches, offset, "chunk",
                                      block_tab=block_tab, kv_span=kv_span)
-        x = L.rms_norm(x[:, -1:], self.top["final_norm"], cfg.norm_eps)
-        logits = transformer.unembed(self.top, cfg, x, None)[:, 0]
-        return logits, new_caches
+        return self._tail(x[:, -1:]), new_caches
 
     # per-layer cache helpers (unstacked layout)
     def init_caches(self, batch: int, cache_len: int, dtype=jnp.float32):

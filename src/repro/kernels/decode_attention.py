@@ -127,5 +127,6 @@ def decode_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(kv_len, qg, kt, vt)
     return out.reshape(b, h, d)

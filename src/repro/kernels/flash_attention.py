@@ -148,5 +148,6 @@ def flash_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
         interpret=interpret,
+        name="flash_prefill",
     )(kv_len, qt, kt, vt)
     return out.transpose(0, 2, 1, 3)   # (B, Sq, H, D)

@@ -203,5 +203,6 @@ def paged_decode_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(*operands)
     return out.reshape(b, h, d)

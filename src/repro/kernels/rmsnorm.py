@@ -43,5 +43,6 @@ def rmsnorm_pallas(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6,
         out_specs=pl.BlockSpec((block_r, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, w)
     return out[:r].reshape(shape)

@@ -150,6 +150,7 @@ def topk_pallas(
             pltpu.VMEM((block_q, LANES), jnp.int32),
         ],
         interpret=interpret,
+        name="topk_sweep",
     )(queries, database)
     return scores[:qn, :k], idx[:qn, :k]
 
@@ -219,5 +220,6 @@ def topk_merge_pallas(
             jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         ],
         interpret=interpret,
+        name="topk_merge",
     )(flat_m, flat_s, flat_i)
     return scores[:qn, :k], idx[:qn, :k]

@@ -19,6 +19,12 @@ Perfetto timeline:
   → swap out/in render as one per-request timeline across threads.
   ``current_scope()`` lets code that hops threads (the streamer's I/O
   worker) carry the ids across explicitly.
+* ``Tracer.interval(name, **attrs)`` — context manager emitting one
+  complete (``X``) event at exit, start and duration together;
+  ``Tracer.complete(name, start, end)`` records one for an interval
+  timed elsewhere (``perf_counter`` seconds), such as a JAX compile
+  reported after it ended (:mod:`repro.obs.jitlog`).  Readers that pair
+  ``B``/``E`` events never see them.
 * ``Tracer.instant(name)`` / ``Tracer.counter(name, value)`` — point
   events and counter tracks.
 
@@ -82,6 +88,13 @@ class NullTracer:
     def current_scope(self) -> Tuple:
         return ()
 
+    def interval(self, name: str, **attrs) -> _NullSpan:
+        return NULL_SPAN
+
+    def complete(self, name: str, start: float, end: float,
+                 **attrs) -> None:
+        pass
+
     def begin(self, name: str, **attrs) -> None:
         return None
 
@@ -123,6 +136,26 @@ class _Span:
         return False
 
 
+class _Interval:
+    """One live complete (``X``) event; created per ``Tracer.interval``."""
+    __slots__ = ("_tr", "_name", "_attrs", "_t0")
+
+    def __init__(self, tr: "Tracer", name: str,
+                 attrs: Optional[Dict[str, Any]]):
+        self._tr = tr
+        self._name = name
+        self._attrs = attrs
+
+    def __enter__(self) -> "_Interval":
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._tr._record_complete(self._name, self._t0,
+                                  time.perf_counter(), self._attrs)
+        return False
+
+
 class _Scope:
     """Thread-local trace-id scope pushed by ``Tracer.scope``."""
     __slots__ = ("_tr", "_ids")
@@ -161,8 +194,14 @@ class Tracer:
 
     # ------------------------------------------------------------- record
     def _record(self, ph: str, name: str, attrs: Optional[Dict[str, Any]],
-                aid: Optional[int] = None) -> None:
-        ts = (time.perf_counter() - self._t0) * 1e6   # microseconds
+                aid: Optional[float] = None,
+                start: Optional[float] = None) -> None:
+        """Append one event stamped now, or at ``start`` (a
+        ``perf_counter`` time).  ``aid`` is the async id of ``b``/``e``
+        events and the duration in microseconds of ``X`` events."""
+        if start is None:
+            start = time.perf_counter()
+        ts = (start - self._t0) * 1e6   # microseconds
         tid = threading.get_ident()
         with self._lock:
             if tid not in self._tnames:
@@ -171,16 +210,38 @@ class Tracer:
                 self.dropped += 1
             self._ring.append((ph, name, ts, tid, aid, attrs))
 
+    def _record_complete(self, name: str, start: float, end: float,
+                         attrs: Optional[Dict[str, Any]]) -> None:
+        self._record("X", name, attrs, aid=max(end - start, 0.0) * 1e6,
+                     start=start)
+
+    def _tagged(self, attrs: Dict[str, Any]) -> Optional[Dict[str, Any]]:
+        """``attrs`` plus the ambient :meth:`scope` ids as
+        ``trace_ids``, unless the caller passed ``trace_id`` or
+        ``trace_ids``; None when empty."""
+        if "trace_id" not in attrs and "trace_ids" not in attrs:
+            ids = self.current_scope()
+            if ids:
+                attrs["trace_ids"] = list(ids)
+        return attrs or None
+
     # ------------------------------------------------------------- public
     def span(self, name: str, **attrs) -> _Span:
         """Duration span on the current thread's track.  Tags the
         ambient :meth:`scope` trace ids as ``args.trace_ids`` unless the
         caller passed explicit ``trace_id``/``trace_ids``."""
-        if "trace_id" not in attrs and "trace_ids" not in attrs:
-            ids = self.current_scope()
-            if ids:
-                attrs["trace_ids"] = list(ids)
-        return _Span(self, name, attrs or None)
+        return _Span(self, name, self._tagged(attrs))
+
+    def interval(self, name: str, **attrs) -> _Interval:
+        """Complete (``X``) event on the current thread's track, recorded
+        at exit; tags the ambient scope like :meth:`span`."""
+        return _Interval(self, name, self._tagged(attrs))
+
+    def complete(self, name: str, start: float, end: float,
+                 **attrs) -> None:
+        """Record a complete (``X``) event on the current thread's track
+        for ``[start, end]`` in ``time.perf_counter`` seconds."""
+        self._record_complete(name, start, end, self._tagged(attrs))
 
     def scope(self, *trace_ids) -> _Scope:
         """Tag every span opened inside with these request/trace ids."""
@@ -193,12 +254,8 @@ class Tracer:
 
     def begin(self, name: str, **attrs) -> Tuple[str, int]:
         """Open an async span that may :meth:`end` on another thread."""
-        if "trace_id" not in attrs and "trace_ids" not in attrs:
-            ids = self.current_scope()
-            if ids:
-                attrs["trace_ids"] = list(ids)
         aid = next(self._ids)
-        self._record("b", name, attrs or None, aid=aid)
+        self._record("b", name, self._tagged(attrs), aid=aid)
         return (name, aid)
 
     def end(self, token: Optional[Tuple[str, int]]) -> None:
@@ -210,11 +267,7 @@ class Tracer:
         self._record("e", name, None, aid=aid)
 
     def instant(self, name: str, **attrs) -> None:
-        if "trace_id" not in attrs and "trace_ids" not in attrs:
-            ids = self.current_scope()
-            if ids:
-                attrs["trace_ids"] = list(ids)
-        self._record("i", name, attrs or None)
+        self._record("i", name, self._tagged(attrs))
 
     def counter(self, name: str, value: float) -> None:
         self._record("C", name, {"value": float(value)})
@@ -245,7 +298,9 @@ class Tracer:
             ev: Dict[str, Any] = {"name": name, "cat": "repro", "ph": ph,
                                   "ts": round(ts, 3), "pid": pid,
                                   "tid": tid}
-            if aid is not None:
+            if ph == "X":
+                ev["dur"] = round(aid, 3)
+            elif aid is not None:
                 ev["id"] = aid
             if ph == "i":
                 ev["s"] = "t"          # thread-scoped instant

@@ -356,7 +356,7 @@ class ShardedIVFStore:
                 board_s, board_i, searched = store.sweep_boards(
                     queries, own, top_k, impl=impl,
                     streamer=shard.streamer, stats=shard_stats,
-                    hot=shard.hot, qmask=qmask)
+                    hot=shard.hot, qmask=qmask, tracer=self.tracer)
             if stats:
                 stats.merge(shard_stats)
             s, i = ops.retrieval_topk_merge(

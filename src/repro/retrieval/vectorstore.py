@@ -31,6 +31,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.kernels import ops
+from repro.obs.trace import NULL_SPAN, NULL_TRACER
 
 
 @dataclass
@@ -385,7 +386,8 @@ class VectorStore:
                nprobe: Optional[int] = None,
                streamer=None,
                stats: Optional[SearchStats] = None,
-               hot=None) -> Tuple[np.ndarray, np.ndarray]:
+               hot=None, tracer=NULL_TRACER
+               ) -> Tuple[np.ndarray, np.ndarray]:
         """Top-k across the probed partitions (default: all ⇒ exact).
 
         ``nprobe`` prunes to the closest clusters (IVF); ``streamer``
@@ -395,7 +397,8 @@ class VectorStore:
         on-demand cache behaviour.  ``hot`` (a
         :class:`~repro.retrieval.cache.HotPartitionSet`) answers probed
         partitions that are promoted device-resident without touching the
-        host tier at all.  Returns (scores (Q, k), global chunk ids
+        host tier at all.  ``tracer`` records a ``topk`` span per
+        partition sweep.  Returns (scores (Q, k), global chunk ids
         (Q, k)).
         """
         nq = queries.shape[0]
@@ -417,7 +420,7 @@ class VectorStore:
 
         board_s, board_i, searched = self.sweep_boards(
             queries, pids, top_k, impl=impl, streamer=streamer, stats=stats,
-            hot=hot, qmask=qmask)
+            hot=hot, qmask=qmask, tracer=tracer)
         scores, gids = ops.retrieval_topk_merge(
             board_s, board_i, qmask & searched[None, :], top_k, impl=impl)
         return np.asarray(scores), np.asarray(gids)
@@ -425,7 +428,8 @@ class VectorStore:
     def sweep_boards(self, queries: np.ndarray, pids: Sequence[int],
                      top_k: int, impl: Optional[str] = None,
                      streamer=None, stats: Optional[SearchStats] = None,
-                     hot=None, qmask: Optional[np.ndarray] = None
+                     hot=None, qmask: Optional[np.ndarray] = None,
+                     tracer=NULL_TRACER
                      ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Per-partition top-k sweep over ``pids`` without the merge.
 
@@ -450,6 +454,10 @@ class VectorStore:
         again even if a kernel raises or the caller's streamer is torn
         down mid-sweep (try/finally) — an aborted sweep must not leak
         host memory.
+
+        With ``tracer`` enabled, each partition's ``ops.retrieval_topk``
+        call and the read of its result record a ``topk`` span
+        (``pid``, ``rows``).
         """
         nq = queries.shape[0]
         q = queries.astype(np.float32)
@@ -466,6 +474,10 @@ class VectorStore:
             return (float(qmask[:, pid].sum()) if qmask is not None
                     else 1.0)
 
+        def topk_span(pid: int, rows: int):
+            return (tracer.interval("topk", pid=pid, rows=rows)
+                    if tracer.enabled else NULL_SPAN)
+
         hot_entries = {}
         if hot is not None:
             for pid in pids:
@@ -476,9 +488,10 @@ class VectorStore:
             t0 = time.perf_counter()
             k_eff = min(top_k, int(dev_emb.shape[0]))
             if k_eff > 0:
-                s, i = ops.retrieval_topk(q, dev_emb, k_eff, impl=impl)
-                board_s[:, pid, :k_eff] = np.asarray(s)
-                board_i[:, pid, :k_eff] = doc_ids[np.asarray(i)]
+                with topk_span(pid, int(dev_emb.shape[0])):
+                    s, i = ops.retrieval_topk(q, dev_emb, k_eff, impl=impl)
+                    board_s[:, pid, :k_eff] = np.asarray(s)
+                    board_i[:, pid, :k_eff] = doc_ids[np.asarray(i)]
             searched[pid] = True
             if stats:
                 stats.add(search_seconds=time.perf_counter() - t0,
@@ -517,10 +530,11 @@ class VectorStore:
                 t0 = time.perf_counter()
                 k_eff = min(top_k, p.embeddings.shape[0])
                 if k_eff > 0:
-                    s, i = ops.retrieval_topk(q, p.embeddings, k_eff,
-                                              impl=impl)
-                    board_s[:, pid, :k_eff] = np.asarray(s)
-                    board_i[:, pid, :k_eff] = p.doc_ids[np.asarray(i)]
+                    with topk_span(pid, int(p.embeddings.shape[0])):
+                        s, i = ops.retrieval_topk(q, p.embeddings, k_eff,
+                                                  impl=impl)
+                        board_s[:, pid, :k_eff] = np.asarray(s)
+                        board_i[:, pid, :k_eff] = p.doc_ids[np.asarray(i)]
                 searched[pid] = True
                 if stats:
                     stats.add(search_seconds=time.perf_counter() - t0,

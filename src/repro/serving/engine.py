@@ -53,6 +53,7 @@ from repro.core.pipeline import (Pipeline, PipelineWorker, StageQueue,
 from repro.core.placement import Placement, PlacementOptimizer
 from repro.core.prefetch import PrefetchPolicy
 from repro.core.scheduler import BacklogScheduler
+from repro.obs import jitlog
 from repro.obs.metrics import MetricsRegistry, NULL_REGISTRY
 from repro.obs.trace import NULL_TRACER
 from repro.retrieval.cache import HotPartitionSet, PartitionCache
@@ -117,6 +118,9 @@ class RagdollEngine:
                 self.opt.registry = self.registry
         if hasattr(generator, "bind_obs"):
             generator.bind_obs(self.tracer, self.registry)
+        # JAX traces and compiles land in this engine's registry (and
+        # tracer, when one is bound) until ``stop``
+        jitlog.attach(self)
         p0 = (initial_partitions if initial_partitions is not None
               else len(store.partitions))
         self.pcache = PartitionCache(store, target=p0)
@@ -169,7 +173,8 @@ class RagdollEngine:
                 step_fn=self._generate_step,
                 on_policy_boundary=self._gen_boundary,
                 policy_every=policy_every,
-                on_error=self._worker_failed)
+                on_error=self._worker_failed,
+                tracer_fn=lambda: self.tracer)
             self.pipeline = Pipeline(retrieval_queue=rq, context_queue=cq,
                                      done_queue=dq, workers=[rw, gw])
         else:
@@ -203,7 +208,8 @@ class RagdollEngine:
                 else:
                     scores, ids = self.store.search(
                         queries, reqs[0].top_k, nprobe=self.nprobe,
-                        streamer=self.streamer, stats=stats, hot=self.hot)
+                        streamer=self.streamer, stats=stats, hot=self.hot,
+                        tracer=self.tracer)
             chunks = self.store.get_chunks(ids)
             t1 = time.perf_counter()
         if self.registry.enabled:
@@ -255,7 +261,7 @@ class RagdollEngine:
         if self.scheduler is not None:
             self.scheduler.tick()       # resume parked work if room
         stepped = self.generator.step()
-        finished = self.generator.harvest()
+        finished = self.generator.harvest_stamped()
         if not stepped and not finished:
             return None            # idle: no live slots
         t = time.perf_counter()
@@ -268,8 +274,9 @@ class RagdollEngine:
         if stepped and self.registry.enabled:
             self.registry.histogram("decode.step_seconds").observe(t - t0)
         done: List[Request] = []
-        for req, text, _tokens in finished:
+        for req, text, _tokens, t_first in finished:
             req.output = text
+            req.t_first_token = t_first
             req.t_gen_end = t
             done.append(req)
         if done:
@@ -454,6 +461,7 @@ class RagdollEngine:
 
     def stop(self) -> None:
         self.pipeline.stop()
+        jitlog.detach(self)
         if self._owns_streamer:     # an injected streamer outlives us
             self.streamer.close()
         if self.sharded is not None:

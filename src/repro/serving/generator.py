@@ -37,6 +37,7 @@ Slot lifecycle::
 from __future__ import annotations
 
 import hashlib
+import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -166,6 +167,7 @@ class SlotState:
     pos: int                      # absolute position: ctx_len + emitted
     remaining: int                # decode steps left in the token budget
     tokens: List[int] = field(default_factory=list)
+    t_first_token: Optional[float] = None   # perf_counter at token one
 
 
 @dataclass(frozen=True)
@@ -323,6 +325,7 @@ class _Parked:
     cur: int                  # pending token awaiting its KV write
     dec_pos: int              # _pos value: the next decode position
     trace_ids: Tuple = ()     # request trace scope, restored on resume
+    t_first_token: Optional[float] = None
 
 
 class ContinuousGenerator(_GeneratorBase):
@@ -474,7 +477,8 @@ class ContinuousGenerator(_GeneratorBase):
         # host-side per-slot scalars (tiny; converted per step)
         self._cur = np.zeros(num_slots, np.int32)
         self._pos = np.zeros(num_slots, np.int32)
-        self._finished: List[Tuple[Any, str, List[int]]] = []
+        # (key, text, tokens, t_first_token) of rows that left
+        self._finished: List[Tuple[Any, str, List[int], float]] = []
         self.steps = 0
 
     # ------------------------------------------------------------ helpers
@@ -486,6 +490,8 @@ class ContinuousGenerator(_GeneratorBase):
             self.tracer = tracer
             if self.kv is not None:
                 self.kv.tracer = tracer
+            if self.exec is not None:
+                self.exec.tracer = tracer
         if registry is not None:
             self.registry = registry
             if self.kv is not None:
@@ -577,6 +583,8 @@ class ContinuousGenerator(_GeneratorBase):
     def _emit(self, ref: SlotRef, token: int) -> None:
         """Append one token; finish + free the slot on EOS / budget end."""
         st = self.table.advance(ref, token)
+        if st.t_first_token is None:
+            st.t_first_token = time.perf_counter()
         self._cur[ref.index] = token
         # st.pos counts ctx_len + emitted tokens; the emitted token is
         # *pending* its KV write, so the next decode call runs at pos-1
@@ -593,7 +601,8 @@ class ContinuousGenerator(_GeneratorBase):
                 self.kv.release(ref.index)
             self._slot_scope.pop(ref.index, None)
             self._finished.append(
-                (st.key, self.tok.decode(st.tokens), list(st.tokens)))
+                (st.key, self.tok.decode(st.tokens), list(st.tokens),
+                 st.t_first_token))
 
     # ------------------------------------------------------------- public
     def join(self, key: Any, prompt: str,
@@ -918,7 +927,9 @@ class ContinuousGenerator(_GeneratorBase):
                 else:
                     logits, self.cache = self._decode(self.params, cur,
                                                       self.cache, pos)
-            nxt = self._greedy(logits)
+            with (self.tracer.interval("decode.sync")
+                  if self.tracer.enabled else NULL_SPAN):
+                nxt = self._greedy(logits)
         if (self.paged and self.registry.enabled
                 and self.kv.kv_format == "int8"):
             # dequant traffic: this step's fused kernel read every live
@@ -1006,7 +1017,8 @@ class ContinuousGenerator(_GeneratorBase):
         self._parked[handle] = _Parked(
             key=st.key, tokens=list(st.tokens), pos=st.pos,
             remaining=st.remaining, cur=int(self._cur[ref.index]),
-            dec_pos=int(self._pos[ref.index]), trace_ids=tuple(scope))
+            dec_pos=int(self._pos[ref.index]), trace_ids=tuple(scope),
+            t_first_token=st.t_first_token)
         # the freed row keeps riding the batched decode like any dead
         # slot; its block-table row now points at the trash page, so the
         # parked writes can never land in a page re-issued to a joiner
@@ -1045,7 +1057,9 @@ class ContinuousGenerator(_GeneratorBase):
             self._pending_resume.add(ref.index)
         if self.tracer.enabled and parked.trace_ids:
             self._slot_scope[ref.index] = parked.trace_ids
-        self.table.state(ref).tokens.extend(parked.tokens)
+        st = self.table.state(ref)
+        st.tokens.extend(parked.tokens)
+        st.t_first_token = parked.t_first_token
         self._cur[ref.index] = parked.cur
         self._pos[ref.index] = parked.dec_pos
         del self._parked[key]
@@ -1164,6 +1178,12 @@ class ContinuousGenerator(_GeneratorBase):
 
     def harvest(self) -> List[Tuple[Any, str, List[int]]]:
         """Drain (key, text, tokens) for rows finished since last call."""
+        return [f[:3] for f in self.harvest_stamped()]
+
+    def harvest_stamped(self) -> List[Tuple[Any, str, List[int], float]]:
+        """Drain (key, text, tokens, t_first_token) for rows finished
+        since last call; ``t_first_token`` is the ``perf_counter`` time
+        the prefill emitted the row's first token."""
         out, self._finished = self._finished, []
         return out
 

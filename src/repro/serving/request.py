@@ -39,6 +39,9 @@ class Request:
     t_ret_end: Optional[float] = None
     t_gen_start: Optional[float] = None
     t_gen_end: Optional[float] = None
+    # the first answer token, emitted by the prefill (the continuous
+    # generator's paths only; None elsewhere)
+    t_first_token: Optional[float] = None
 
     # ------------------------------------------------------------- metrics
     @property

@@ -8,8 +8,13 @@ Contracts pinned here:
 * histogram bucket boundaries are a pure function of their parameters
   (cross-run / cross-shard bucket compatibility);
 * exported traces are valid Chrome/Perfetto JSON — every ``E`` closes a
-  matching ``B``, async ``b``/``e`` pair up across threads — and
-  ``scripts/check_trace.py`` accepts them (and rejects corrupted ones);
+  matching ``B``, async ``b``/``e`` pair up across threads, complete
+  ``X`` events carry their duration — and ``scripts/check_trace.py``
+  accepts them (and rejects corrupted ones);
+* JAX traces and compiles reach the engine's registry always and its
+  tracer when one is bound, through one listener per process
+  (``obs.jitlog``); the first token of every request is stamped between
+  its admission and its harvest;
 * ``SearchStats`` merge conserves totals; partially-timestamped requests
   never crash the latency report.
 """
@@ -52,6 +57,8 @@ def test_null_tracer_is_noop(tmp_path):
     tr.end(token)                       # None token: no-op, no raise
     tr.instant("i")
     tr.counter("c", 1.0)
+    assert tr.interval("x", a=1) is NULL_SPAN
+    tr.complete("x", 0.0, 1.0, a=1)
     assert tr.events() == []
     out = tmp_path / "t.json"
     tr.export(str(out))
@@ -124,6 +131,35 @@ def test_ring_buffer_bounds_memory():
     assert tr.dropped == 32             # 40 events through an 8-slot ring
     with pytest.raises(ValueError):
         Tracer(capacity=0)
+
+
+def test_complete_events_round_trip(tmp_path):
+    """``interval`` records one X event at exit holding start and
+    duration; ``complete`` records one for an interval timed elsewhere;
+    both tag the ambient scope and export with ``dur``."""
+    import time
+    tr = Tracer()
+    with tr.scope(5):
+        with tr.interval("outer", k=1):
+            time.sleep(0.002)
+        t = time.perf_counter()
+        tr.complete("timed", t - 0.5, t, fun="f")
+    (ph, name, ts, tid, dur, attrs), (ph2, name2, ts2, _, dur2, attrs2) = \
+        tr.events()
+    assert (ph, name, ph2, name2) == ("X", "outer", "X", "timed")
+    assert dur >= 2e3 and tid == threading.get_ident()
+    assert attrs == {"k": 1, "trace_ids": [5]}
+    assert attrs2 == {"fun": "f", "trace_ids": [5]}
+    assert dur2 == pytest.approx(0.5e6) and ts2 < ts   # backdated start
+    path = tmp_path / "t.json"
+    tr.export(str(path))
+    rows = [e for e in json.loads(path.read_text())["traceEvents"]
+            if e["ph"] == "X"]
+    assert [e["name"] for e in rows] == ["timed", "outer"]  # ts order
+    assert rows[0]["dur"] == pytest.approx(0.5e6, rel=1e-6)
+    assert "id" not in rows[0]
+    assert _load_checker().check(
+        json.loads(path.read_text()), require=[], any_groups=[]) == []
 
 
 def test_span_balanced_on_exception(tmp_path):
@@ -322,11 +358,10 @@ def tiny_model():
     return cfg, params
 
 
-def _mini_engine_outputs(tiny_model, root, tracer, registry):
-    """Deterministic single-threaded engine drive (fig8's mini-trace
-    shape): retrieve a batch, then pump admit/decode to completion."""
-    import time
-
+def _mini_engine(tiny_model, root, tracer, registry, **gen_kw):
+    """A mini engine over a 40-chunk store (3 of 4 partitions spilled)
+    and a paged continuous generator; ``gen_kw`` adds generator
+    options (streamed weights, chunked prefill)."""
     from repro.core.scheduler import BacklogScheduler
     from repro.retrieval import HashEmbedder, VectorStore
     from repro.serving.engine import RagdollEngine
@@ -340,11 +375,21 @@ def _mini_engine_outputs(tiny_model, root, tracer, registry):
     store.spill(3)
     gen = ContinuousGenerator(
         cfg, params, GeneratorConfig(ctx_len=16, max_new_tokens=4),
-        num_slots=2, streamed=False, paged=True, page_size=4)
-    eng = RagdollEngine(store, emb, gen, BacklogScheduler(max_batch=8),
-                        BacklogScheduler(max_batch=2),
-                        initial_partitions=2, tracer=tracer,
-                        registry=registry)
+        num_slots=2, paged=True, page_size=4, **gen_kw)
+    return RagdollEngine(store, emb, gen, BacklogScheduler(max_batch=8),
+                         BacklogScheduler(max_batch=2),
+                         initial_partitions=2, tracer=tracer,
+                         registry=registry)
+
+
+def _mini_engine_outputs(tiny_model, root, tracer, registry, **gen_kw):
+    """Deterministic single-threaded engine drive (fig8's mini-trace
+    shape): retrieve a batch, then pump admit/decode to completion."""
+    import time
+
+    from repro.obs import jitlog
+
+    eng = _mini_engine(tiny_model, root, tracer, registry, **gen_kw)
     reqs = [Request(rid=i, query=f"query {i}", arrival=time.perf_counter())
             for i in range(4)]
     try:
@@ -360,7 +405,14 @@ def _mini_engine_outputs(tiny_model, root, tracer, registry):
             assert guard < 400, "mini engine stalled"
     finally:
         eng.streamer.close()
+        jitlog.detach(eng)
     return {r.rid: r.output for r in eng.completed}, eng
+
+
+def _assert_first_token_stamped(eng):
+    assert len(eng.completed) == 4
+    for r in eng.completed:
+        assert r.t_gen_start <= r.t_first_token <= r.t_gen_end
 
 
 def test_engine_tracing_is_token_identical(tiny_model, tmp_path):
@@ -384,8 +436,13 @@ def test_engine_tracing_is_token_identical(tiny_model, tmp_path):
     assert chk.check(doc) == []         # default per-request coverage
     names = {e["name"] for e in doc["traceEvents"] if e["ph"] != "M"}
     for required in ("request", "retrieve.batch", "embed", "search",
-                     "prefill", "decode.step"):
+                     "prefill", "decode.step", "decode.sync", "topk",
+                     "jit.trace"):
         assert required in names, required
+    topk = [e for e in doc["traceEvents"] if e["name"] == "topk"]
+    assert all(e["ph"] == "X" and e["args"]["rows"] > 0 for e in topk)
+    assert {e["args"]["pid"] for e in topk} <= set(range(4))
+    _assert_first_token_stamped(eng)
 
     snap = eng.metrics_snapshot()
     assert snap["counters"]["engine.retrieve_batches"] >= 1.0
@@ -396,3 +453,173 @@ def test_engine_tracing_is_token_identical(tiny_model, tmp_path):
     assert snap["histograms"]["request.latency_seconds"]["count"] == 4
     # engine-owned registry keeps the policy journal seam alive
     assert eng.policy_trace == []       # pump_once skips the boundary
+
+
+# ------------------------------------------- idle and compile attribution
+
+_STREAMED = dict(streamed=True, resident_layers=1)
+
+
+@pytest.mark.parametrize("gen_kw", [
+    {"prefill_chunk": 8},
+    dict(_STREAMED),
+    dict(_STREAMED, prefill_chunk=8),
+], ids=["paged-chunked", "streamed", "streamed-chunked"])
+def test_engine_new_spans_are_token_identical(tiny_model, tmp_path, gen_kw):
+    """The step's host spans (``step.head``/``step.tail``/``stream.wait``
+    on the streamed executor, ``decode.sync``, ``topk``) and the
+    ``jit.*`` spans perturb no token; every request's first token is
+    stamped between admission and harvest, chunked or not."""
+    from repro.core.prefetch import PrefetchPolicy
+
+    def kw():   # a queue one layer deep waits on every streamed layer
+        return dict(gen_kw, policy=PrefetchPolicy(max_depth=1)) \
+            if gen_kw.get("streamed") else dict(gen_kw)
+    out_off, eng_off = _mini_engine_outputs(
+        tiny_model, str(tmp_path / "off"), None, None, **kw())
+    tr = Tracer()
+    out_on, eng = _mini_engine_outputs(
+        tiny_model, str(tmp_path / "on"), tr, MetricsRegistry(), **kw())
+    assert out_on == out_off and len(out_on) == 4
+    names = {e[1] for e in tr.events()}
+    want = {"decode.sync", "topk", "jit.trace", "jit.lower", "jit.compile"}
+    if gen_kw.get("streamed"):
+        want |= {"step.head", "step.tail", "stream.wait"}
+    if gen_kw.get("prefill_chunk"):
+        want.add("prefill.chunk")
+    assert want <= names, want - names
+    for e in eng, eng_off:
+        _assert_first_token_stamped(e)
+    # the counters run with tracing off too
+    assert eng_off.registry.counter("jit.events").value > 0
+
+
+def test_jitlog_spans_on_the_calling_thread():
+    """A fresh ``jax.jit`` closure and eager dispatch on a new shape
+    each report trace, lower and compile on the thread that ran them,
+    with ``fun``; inner primitives nest inside the closure's trace.
+    Every attached sink's registry counts every event; a sink with no
+    tracer bound records no span."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.obs import jitlog
+
+    class Sink:
+        pass
+    on, off = Sink(), Sink()
+    on.tracer, on.registry = Tracer(), MetricsRegistry()
+    off.tracer, off.registry = NULL_TRACER, MetricsRegistry()
+    jitlog.attach(on)
+    jitlog.attach(off)
+
+    def work():
+        jax.jit(lambda x: jnp.cos(x) * 3.0)(jnp.arange(5.0))
+        jnp.sin(jnp.ones((3, 7, 11)))
+    th = threading.Thread(target=work)
+    try:
+        th.start()
+        th.join(timeout=60.0)
+        assert not th.is_alive()
+    finally:
+        jitlog.detach(on)
+        jitlog.detach(off)
+    assert on not in jitlog.sinks() and off not in jitlog.sinks()
+    evs = [e for e in on.tracer.events() if e[3] == th.ident]
+    assert all(e[0] == "X" for e in evs)
+    by_fun = {}
+    for ph, name, ts, tid, dur, attrs in evs:
+        by_fun.setdefault(attrs["fun"], []).append((name, ts, dur))
+    lam = by_fun["<lambda>"] + by_fun["jit(<lambda>)"]
+    assert sorted(n for n, _, _ in lam) == ["jit.compile", "jit.lower",
+                                            "jit.trace"]
+    assert {n for n, _, _ in by_fun["jit(sin)"]} == {"jit.lower",
+                                                     "jit.compile"}
+    assert "sin" in by_fun and "cos" in by_fun
+    (_, o_ts, o_dur), = [x for x in by_fun["<lambda>"]
+                         if x[0] == "jit.trace"]
+    (_, i_ts, i_dur), = by_fun["cos"]
+    slack = 2e3                          # two clocks: 2 ms of room
+    assert o_ts - slack <= i_ts and i_ts + i_dur <= o_ts + o_dur + slack
+    n = on.registry.counter("jit.events").value
+    assert n >= len(evs) > 0
+    assert off.registry.counter("jit.events").value == n
+    assert on.registry.counter("jit.seconds").value > 0
+
+
+def test_jitlog_one_listener_for_many_engines(tiny_model, tmp_path):
+    from jax._src import monitoring
+
+    from repro.obs import jitlog
+    engines = [_mini_engine(tiny_model, str(tmp_path / str(i)), None, None)
+               for i in range(2)]
+    assert monitoring._event_time_span_listeners.count(
+        jitlog._on_event) == 1
+    assert all(e in jitlog.sinks() for e in engines)
+    engines[0].streamer.close()
+    jitlog.detach(engines[0])
+    assert engines[0] not in jitlog.sinks() and engines[1] in jitlog.sinks()
+    engines[1].streamer.close()
+    jitlog.detach(engines[1])
+
+
+def test_pump_records_wait_and_step_spans():
+    """Idle sleeps are ``pump.wait``, iterations that admit or step are
+    ``pump.step``; the tracer is read on every iteration, so binding it
+    late works, and the two never overlap on the pump's thread."""
+    import time
+
+    from repro.core.pipeline import StageQueue, StepPumpWorker
+
+    class Holder:
+        tracer = NULL_TRACER
+    holder, live = Holder(), []
+
+    def step():
+        return [live.pop()] if live else None
+    cq, dq = StageQueue("context"), StageQueue("done")
+    pump = StepPumpWorker("generation", cq, dq, capacity_fn=lambda: 1,
+                          admit_fn=live.extend, step_fn=step,
+                          idle_wait=0.002,
+                          tracer_fn=lambda: holder.tracer)
+    tr = Tracer()
+    pump.start()
+    try:
+        time.sleep(0.02)
+        holder.tracer = tr
+        time.sleep(0.02)
+        cq.put_many(range(3))
+        deadline = time.perf_counter() + 5.0
+        while len(dq) < 3 and time.perf_counter() < deadline:
+            time.sleep(0.002)
+        time.sleep(0.02)
+    finally:
+        pump.stop()
+        pump.join(timeout=5.0)
+    assert not pump.is_alive() and len(dq) == 3
+    evs = sorted((e for e in tr.events() if e[0] == "X"),
+                 key=lambda e: e[2])
+    assert {e[3] for e in evs} == {pump.ident}
+    names = [e[1] for e in evs]
+    assert names.count("pump.step") == 3 and "pump.wait" in names
+    for a, b in zip(evs, evs[1:]):
+        assert a[2] + a[4] <= b[2] + 1e-3        # disjoint, in order
+
+
+def test_first_token_survives_preempt_and_resume(tiny_model):
+    from repro.serving.generator import (ContinuousGenerator,
+                                         GeneratorConfig)
+    cfg, params = tiny_model
+    cont = ContinuousGenerator(cfg, params,
+                               GeneratorConfig(ctx_len=16, max_new_tokens=5),
+                               num_slots=2, paged=True, page_size=4)
+    ref = cont.join("x", "alpha beta")
+    t_first = cont.table.state(ref).t_first_token
+    assert t_first is not None
+    cont.step()
+    ref = cont.resume(cont.preempt(ref))
+    assert cont.table.state(ref).t_first_token == t_first
+    while cont.active_slots:
+        cont.step()
+    ((key, _, tokens, stamp),) = cont.harvest_stamped()
+    assert key == "x" and len(tokens) == 5 and stamp == t_first
