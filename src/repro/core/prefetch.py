@@ -130,6 +130,53 @@ def layer_program(cfg: ModelConfig, kind, mode: str,
     return jax.jit(fn)
 
 
+def final_logits(top, cfg: ModelConfig, x):
+    """Final norm and unembedding of ``x`` (B, 1, D): logits (B, V)."""
+    x = L.rms_norm(x, top["final_norm"], cfg.norm_eps)
+    return transformer.unembed(top, cfg, x, None)[:, 0]
+
+
+def prefill_programs(cfg: ModelConfig, kind, row_dtype):
+    """The jitted programs of a batch-1 prefill that builds its row
+    caches itself: ``embed(top, tokens) -> x``, ``layer(lp, x) -> (x,
+    row)`` for layers of ``kind`` and ``pick(top, x) -> (token (1,),
+    finite)``.
+
+    ``layer`` runs :func:`layer_program`'s prefill on a zero
+    ``row_dtype`` row cache of the prompt's length made inside the
+    program, so it computes the same numbers without the caller making
+    and passing the zeros.  ``pick`` is the final norm of the last
+    position, the unembedding and the greedy pick, with whether every
+    logit is finite.  Nothing depends on the KV pool's shape, which
+    changes at every policy boundary: each compiles once per prompt
+    length.
+    """
+    from repro.models import model as M
+
+    def embed(top, tokens):
+        return transformer._embed_inputs(top, cfg, tokens)
+
+    def layer(lp, x):
+        row = jax.tree.map(
+            lambda sd: jnp.zeros(sd.shape, sd.dtype),
+            M._layer_cache_spec(cfg, kind[0], 1, x.shape[1], row_dtype,
+                                None))
+        x, row, _ = transformer.apply_layer(
+            lp, x, cfg, kind, mode="prefill", cache=row, pos=None,
+            ctx=None, moe_strategy="tp")
+        return x, row
+
+    def pick(top, x):
+        logits = final_logits(top, cfg, x[:, -1:])
+        # the pick reads the logits rounded to their dtype, as it does
+        # after a separate unembedding; fused, XLA may keep them wider
+        logits = jax.lax.optimization_barrier(logits)
+        return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                jnp.isfinite(logits).all())
+
+    return jax.jit(embed), jax.jit(layer), jax.jit(pick)
+
+
 def _unstack(tree, reps: int) -> List[Any]:
     """Split stacked (R, ...) params into R per-layer pytrees (host-side)."""
     leaves, treedef = jax.tree.flatten(tree)
@@ -151,6 +198,10 @@ class StreamedExecutor:
     ``params`` is either the stacked pytree of ``Model.init`` or the
     per-layer form of :func:`init_layered_params` (a ``"layers"`` list);
     either way, streamed layers are moved to pinned host memory here.
+
+    :meth:`prefill_rows` runs a batch-1 prefill as compiled programs
+    that build their own row caches: the embedding, each layer, and the
+    final norm, unembedding and greedy pick.
 
     With a ``tracer`` bound, each step records the host side of its eager
     ends: ``step.head`` (the embedding), ``step.tail`` (final norm and
@@ -218,11 +269,18 @@ class StreamedExecutor:
                                                    kv_span)
         return self._apply_cache[key]
 
-    def _stream(self, x, caches, pos, mode: str, block_tab=None,
-                kv_span=None):
-        depth = self.policy.depth(
-            "prefill" if mode in ("prefill", "chunk") else "decode",
-            self.free_bytes, self.layer_bytes)
+    def _prefill_fns(self, kind, row_dtype):
+        key = ("prefill", kind, jnp.dtype(row_dtype))
+        if key not in self._apply_cache:
+            self._apply_cache[key] = prefill_programs(self.cfg, kind,
+                                                      row_dtype)
+        return self._apply_cache[key]
+
+    def _run_layers(self, x, phase: str, call):
+        """Run every layer on ``x``, each as ``x = call(i, kind, lp, x)``,
+        staging streamed layers through the host->device queue at
+        ``phase``'s depth."""
+        depth = self.policy.depth(phase, self.free_bytes, self.layer_bytes)
         staged: Dict[int, Any] = {}
 
         def ensure(i):
@@ -238,15 +296,11 @@ class StreamedExecutor:
         # warm the queue
         for i in range(min(depth, self.n_layers)):
             ensure(i)
-        new_caches = []
         for i in range(self.n_layers):
             kind, _ = self.layers[i]
             lp = staged.pop(i)
-            cache_i = caches[i] if caches is not None else None
             x_prev = x
-            x, nc, _ = self._apply_fn(kind, mode, kv_span)(
-                lp, x, cache_i, pos, block_tab)
-            new_caches.append(nc)
+            x = call(i, kind, lp, x)
             if self.resident <= i + depth < self.n_layers:
                 # dispatch is asynchronous, so without this wait the host
                 # would issue every copy at once.  Layer i is queued, so
@@ -256,6 +310,21 @@ class StreamedExecutor:
                 with self._span("stream.wait", layer=i):
                     jax.block_until_ready(x_prev)
             ensure(i + depth)           # keep the queue full
+        return x
+
+    def _stream(self, x, caches, pos, mode: str, block_tab=None,
+                kv_span=None):
+        new_caches = []
+
+        def call(i, kind, lp, x):
+            cache_i = caches[i] if caches is not None else None
+            x, nc, _ = self._apply_fn(kind, mode, kv_span)(
+                lp, x, cache_i, pos, block_tab)
+            new_caches.append(nc)
+            return x
+
+        phase = "prefill" if mode in ("prefill", "chunk") else "decode"
+        x = self._run_layers(x, phase, call)
         return x, (new_caches if caches is not None else None)
 
     # ------------------------------------------------------------- public
@@ -270,13 +339,33 @@ class StreamedExecutor:
     def _tail(self, x):
         """Final norm and unembedding of ``x`` (B, 1, D)."""
         with self._span("step.tail"):
-            x = L.rms_norm(x, self.top["final_norm"], self.cfg.norm_eps)
-            return transformer.unembed(self.top, self.cfg, x, None)[:, 0]
+            return final_logits(self.top, self.cfg, x)
 
     def prefill(self, inputs, caches: List[dict], enc_embeds=None):
         x = self._head(inputs)
         x, new_caches = self._stream(x, caches, None, "prefill")
         return self._tail(x[:, -1:]), new_caches
+
+    def prefill_rows(self, inputs, row_dtype):
+        """Batch-1 prefill of ``inputs`` (1, S) as compiled programs (see
+        :func:`prefill_programs`): the embedding, one program a layer,
+        streamed layers fed by the prefill-depth queue, and the final
+        norm, unembedding and greedy pick.  Returns ``((token (1,),
+        finite), rows)`` on the device, ``rows`` the per-layer
+        ``row_dtype`` row caches of length S: the host reads back one
+        pair.
+        """
+        kind0 = self.layers[0][0]
+
+        def call(i, kind, lp, x):
+            x, row = self._prefill_fns(kind, row_dtype)[1](lp, x)
+            rows.append(row)
+            return x
+
+        rows: List[Any] = []
+        embed, _, pick = self._prefill_fns(kind0, row_dtype)
+        x = self._run_layers(embed(self.top, inputs), "prefill", call)
+        return pick(self.top, x), rows
 
     def decode(self, inputs, caches: List[dict], pos, slot_mask=None,
                block_tab=None, kv_span=None):

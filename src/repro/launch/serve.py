@@ -9,7 +9,7 @@ configuration's published widths:
   ``StreamedExecutor``;
 * a ``ContinuousGenerator`` with paged bf16 KV, sized by
   :func:`plan_memory` from the device's memory limit and the compiled
-  per-layer programs' own ``memory_analysis()``;
+  join and decode programs' own ``memory_analysis()``;
 * an IVF knowledge base of ``synthetic.blob_corpus`` vectors built through
   ``ArrayEmbedder``, half its partitions spilled to disk, with queries as
   ``synthetic.perturb_queries`` rows appended to the embedder's matrix;
@@ -39,8 +39,8 @@ from repro.core.costmodel import (CostModel, HardwareProfile, ModelProfile,
                                   profile_for_device)
 from repro.core.placement import PlacementOptimizer
 from repro.core.prefetch import (init_layered_params, layer_param_shapes,
-                                 layer_program, top_param_shapes,
-                                 tree_bytes)
+                                 layer_program, prefill_programs,
+                                 top_param_shapes, tree_bytes)
 from repro.core.scheduler import BacklogScheduler
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import layers as L
@@ -68,7 +68,7 @@ class MemoryPlan:
     kv_pages: int           # usable pool pages (+1 trash page row)
     kv_pool_bytes: int      # the pool arrays, every layer
     copy_headroom_bytes: int  # a second pool: un-donated whole-pool copies
-    workspace_bytes: int    # compiled temps + prefill row cache + logits
+    workspace_bytes: int    # compiled temps of a join or a decode step
     retrieval_bytes: int    # the whole corpus, the hot tier's ceiling
     free_bytes: int         # device bytes the prefetch queue may fill
 
@@ -94,10 +94,13 @@ def plan_memory(cfg: ModelConfig, *, device, limit_bytes: int,
 
     The pool holds every page the slot tables can address
     (``num_slots * ceil(total / page_size)``, what a retarget can grow
-    it to), and as much again is left free: joins, resizes and every
-    streamed step write a whole new pool before the old one is released.
-    The workspace is read from the compiled per-layer prefill and decode
-    programs and the unembed.  The streamed layers' in-flight copies
+    it to), and as much again is left free: resizes and every decode
+    step write a whole new pool before the old one is released.  The
+    workspace is the larger of a join's and a decode step's, read from
+    the compiled programs: a join's per-layer prefill program
+    (``prefetch.prefill_programs``) with the other layers' row caches,
+    which it holds until its page write, and the per-layer decode
+    program plus the unembed.  The streamed layers' in-flight copies
     (``QUEUE_DEPTH`` queued + one computing) are reserved, and resident
     layers fill what remains.
     """
@@ -114,8 +117,6 @@ def plan_memory(cfg: ModelConfig, *, device, limit_bytes: int,
 
     kind = cfg.layer_pattern[0]
     lp = _sds(layer_param_shapes(cfg, dtype), on_dev)
-    row = _sds(_layer_cache_spec(cfg, kind[0], 1, total, dtype, None),
-               on_dev)
     x_pre = jax.ShapeDtypeStruct((1, ctx_len, cfg.d_model), dtype,
                                  sharding=on_dev)
     pool = _sds(_layer_cache_spec(cfg, kind[0], pages + 1, page_size,
@@ -125,8 +126,8 @@ def plan_memory(cfg: ModelConfig, *, device, limit_bytes: int,
     pos = jax.ShapeDtypeStruct((num_slots,), jnp.int32, sharding=on_dev)
     tab = jax.ShapeDtypeStruct((num_slots, nmax), jnp.int32,
                                sharding=on_dev)
-    prefill = _compiled_bytes(layer_program(cfg, kind, "prefill"),
-                              lp, x_pre, row, None, None)
+    prefill = _compiled_bytes(prefill_programs(cfg, kind, dtype)[1],
+                              lp, x_pre)
     decode = _compiled_bytes(layer_program(cfg, kind, "decode", total),
                              lp, x_dec, pool, pos, tab)
     top = _sds(top_param_shapes(cfg, dtype), on_dev)
@@ -134,10 +135,10 @@ def plan_memory(cfg: ModelConfig, *, device, limit_bytes: int,
         lambda p, x: transformer.unembed(
             p, cfg, L.rms_norm(x, p["final_norm"], cfg.norm_eps), None)),
         top, x_dec)
-    # the dense batch=1 row cache every full prefill builds, all layers
-    row_cache = cfg.num_layers * tree_bytes(
-        _layer_cache_spec(cfg, kind[0], 1, total, dtype, None))
-    workspace = max(prefill, decode) + head + row_cache
+    # a join holds every other layer's row cache until its page write
+    rows = (cfg.num_layers - 1) * tree_bytes(
+        _layer_cache_spec(cfg, kind[0], 1, ctx_len, dtype, None))
+    workspace = max(prefill + rows, decode + head)
 
     usable = int(limit_bytes * headroom)
     fixed = top_bytes + 2 * pool_bytes + workspace + retrieval_bytes

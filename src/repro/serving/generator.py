@@ -455,7 +455,11 @@ class ContinuousGenerator(_GeneratorBase):
                 kv_format=kv_format, overlap=overlap_swap,
                 tracer=self.tracer, registry=self.registry)
             if streamed:
-                self.caches = self.kv.init_layered(self.exec.layer_kinds())
+                # committed to the device like every program's output, so
+                # the first join and the next share one compiled program
+                self.caches = jax.device_put(
+                    self.kv.init_layered(self.exec.layer_kinds()),
+                    self.exec.device)
             else:
                 self.cache = self.kv.init_stacked()
                 span, ctx_span = total, gen_cfg.ctx_len
@@ -679,16 +683,24 @@ class ContinuousGenerator(_GeneratorBase):
             self._prefix_insert(ref.index, ptoks)
             self._emit(ref, int(self._greedy(logits)[0]))
             return ref
-        with self.tracer.span("prefill", slot=ref.index, tokens=g.ctx_len):
+        # a streamed paged join runs as compiled programs
+        # (``StreamedExecutor.prefill_rows``), then writes the slot's
+        # pages (int8 pools quantize there)
+        fused = self.streamed and self.paged
+        with self.tracer.span("prefill", slot=ref.index, tokens=g.ctx_len,
+                              fused=fused):
             toks = jnp.asarray(ptoks[None])
-            if self.streamed:
+            if fused:
+                out, row = self.exec.prefill_rows(toks, g.dtype)
+                self.caches = self.kv.scatter_row_layered(
+                    self.caches, row, ref.index, g.ctx_len)
+                nxt, finite = jax.device_get(out)
+                self.nonfinite_batches += int(not finite)
+                self.registry.counter("prefill.fused_joins").inc()
+            elif self.streamed:
                 row = self.exec.init_caches(1, self._total, g.dtype)
                 logits, row = self.exec.prefill(toks, row)
-                if self.paged:
-                    self.caches = self.kv.scatter_row_layered(
-                        self.caches, row, ref.index, g.ctx_len)
-                else:
-                    self._scatter_row(row, ref.index)
+                self._scatter_row(row, ref.index)
             else:
                 row = init_cache(self.cfg, 1, self._total, g.dtype)
                 logits, row = self._prefill(self.params, toks, row)
@@ -697,9 +709,11 @@ class ContinuousGenerator(_GeneratorBase):
                         self.cache, row, ref.index, g.ctx_len)
                 else:
                     self._scatter_row(row, ref.index)
+            if not fused:
+                nxt = self._greedy(logits)
         if self.paged:
             self._prefix_insert(ref.index, ptoks)
-        self._emit(ref, int(self._greedy(logits)[0]))
+        self._emit(ref, int(nxt[0]))
         return ref
 
     # --------------------------------------------------- prefix sharing

@@ -79,6 +79,7 @@ bounded by device + host pages rather than device pages alone.
 """
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 import time
@@ -702,6 +703,16 @@ def resize_cache_rows(pools, rows: int):
     return [jax.tree.map(lambda t: fit(t, 0), c) for c in pools]
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _write_rows(caches, row_caches, pages, offs):
+    """Write each layer's batch-1 row ``[0, len(pages))`` into its pool
+    at ``(pages, offs)``; the pools are donated."""
+    n = pages.shape[0]
+    return [jax.tree.map(
+        lambda t, r: t.at[pages, offs].set(r[0, :n].astype(t.dtype)), tc, rc)
+        for tc, rc in zip(caches, row_caches)]
+
+
 @dataclass
 class _SwapJob:
     """One asynchronous swap DMA tracked by the transfer worker.
@@ -1203,7 +1214,9 @@ class PagedKVCache:
 
     def scatter_row_layered(self, caches, row_caches, slot: int,
                             length: int):
-        """Same, for the per-layer list layout of ``StreamedExecutor``."""
+        """Same, for the per-layer list layout of ``StreamedExecutor``:
+        one compiled program over every layer, the pools donated (int8
+        pools quantize eagerly)."""
         self.ensure(slot, length)
         pages, offs = self._page_index(slot, length)
         if self.kv_format == "int8":
@@ -1214,11 +1227,7 @@ class PagedKVCache:
                        for tc, rc in zip(caches, row_caches)]
             self._count_quant(length)
             return out
-        return [
-            jax.tree.map(
-                lambda t, r: t.at[pages, offs].set(
-                    r[0, :length].astype(t.dtype)), tc, rc)
-            for tc, rc in zip(caches, row_caches)]
+        return _write_rows(caches, row_caches, pages, offs)
 
     def _page_index(self, slot: int, length: int):
         idx = np.arange(length)

@@ -72,6 +72,122 @@ def test_continuous_token_identical_streamed(tiny_model, seed):
     assert out == ref
 
 
+def _paged_streamed(cfg, params, resident, kv_format=None, registry=None,
+                    tracer=None):
+    g = GeneratorConfig(ctx_len=CTX, max_new_tokens=MAX_NEW)
+    return ContinuousGenerator(cfg, params, g, num_slots=3, streamed=True,
+                               paged=True, page_size=4,
+                               resident_layers=resident,
+                               kv_format=kv_format, registry=registry,
+                               tracer=tracer)
+
+
+def _eager_join(gen, prompt, slot):
+    """The reference join: the executor's per-layer programs on
+    full-length row caches with the eager embedding and tail, then the
+    pool's scatter of the prompt's rows into ``slot``'s pages.  Returns
+    the first token and the pools."""
+    g = gen.gen_cfg
+    toks = jnp.asarray(gen.tok.encode(prompt, g.ctx_len)[None])
+    logits, row = gen.exec.prefill(
+        toks, gen.exec.init_caches(1, gen._total, g.dtype))
+    assert gen.kv.admit(slot, g.ctx_len + g.max_new_tokens)
+    pools = gen.kv.scatter_row_layered(gen.caches, row, slot, g.ctx_len)
+    return int(gen._greedy(logits)[0]), pools
+
+
+@pytest.mark.parametrize("arch,resident,kv_format", [
+    ("llama3-8b", 2, None),      # every layer resident: one program
+    ("llama3-8b", 1, None),      # resident program, a streamed layer, tail
+    ("llama3-8b", 0, None),      # every layer streamed
+    ("llama3-8b", 1, "bf16"),    # fp32 rows cast into bf16 pages
+    ("llama3-8b", 1, "int8"),    # rows quantized into int8 pages
+    ("chatglm3-6b", 2, None),    # QKV bias, rotary on half the head dims
+])
+def test_compiled_paged_prefill_matches_eager(arch, resident, kv_format):
+    """A streamed paged join run as compiled programs writes the same KV
+    pages, bit for bit, and the same first token as the eager join
+    (per-layer programs on row caches, eager embedding and tail), and
+    serves the same tokens as the whole-batch streamed generator."""
+    cfg = get_config(arch).reduced(num_layers=2)
+    params = Model(cfg, remat=False).init(jax.random.PRNGKey(0),
+                                          jnp.float32)
+    from repro.obs.metrics import MetricsRegistry
+
+    prompt = "alpha beta gamma delta"
+    fused = _paged_streamed(cfg, params, resident, kv_format,
+                            registry=MetricsRegistry())
+    ref = fused.join("first", prompt)
+    first, pools = _eager_join(
+        _paged_streamed(cfg, params, resident, kv_format), prompt,
+        ref.index)
+    assert fused.table.state(ref).tokens == [first]
+    pages = fused.kv.pool.table(ref.index)
+    for a, b in zip(jax.tree.leaves(fused.caches), jax.tree.leaves(pools)):
+        assert np.asarray(a)[pages].any()
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    while fused.active_slots:
+        fused.step()
+    fused.harvest()
+    g = GeneratorConfig(ctx_len=CTX, max_new_tokens=MAX_NEW)
+    prompts = _prompts()
+    out = fused.run(prompts, schedule=_random_schedule(3))
+    if kv_format is None:       # narrower pages attend to rounded keys
+        assert out == Generator(cfg, params, g,
+                                streamed=True).generate(prompts)
+    assert fused.registry.counter("prefill.fused_joins").value == 7
+
+
+def test_compiled_join_compiles_once_and_counts(tiny_model):
+    """A second join of the same prompt length traces, lowers and
+    compiles nothing, and a join after the pool is resized compiles only
+    the page write; ``prefill.fused_joins`` counts the compiled joins,
+    an int8-pool join among them."""
+    from repro.obs import jitlog
+    from repro.obs.metrics import MetricsRegistry
+    from repro.obs.trace import Tracer
+
+    cfg, params = tiny_model
+
+    class Sink:
+        tracer = Tracer()
+        registry = MetricsRegistry()
+
+    sink = Sink()
+    reg = sink.registry
+    jitlog.attach(sink)
+    try:
+        gen = _paged_streamed(cfg, params, 1, registry=reg,
+                              tracer=sink.tracer)
+        gen.join(0, "alpha beta gamma")              # compiles
+        events = reg.counter("jit.events").value
+        assert events > 0
+        n = len(sink.tracer.events())
+        gen.join(1, "delta epsilon zeta eta theta")
+        assert reg.counter("jit.events").value == events
+        assert not [e for e in sink.tracer.events()[n:]
+                    if e[1].startswith("jit.")]
+        assert reg.counter("prefill.fused_joins").value == 2
+        # a policy boundary resizes the pool: only the page write, not
+        # the prefill, compiles again for the new pool shape
+        gen.set_page_budget(gen.kv.pool.capacity + 4)
+        n = len(sink.tracer.events())
+        gen.join(2, "iota kappa lambda")
+        funs = {e[5]["fun"] for e in sink.tracer.events()[n:]
+                if e[1] == "jit.trace"}
+        assert "_write_rows" in funs
+        assert not funs & {"embed", "layer", "pick"}
+        int8 = _paged_streamed(cfg, params, 1, kv_format="int8",
+                               registry=reg, tracer=sink.tracer)
+        int8.join(3, "alpha beta gamma")
+        assert reg.counter("prefill.fused_joins").value == 4
+    finally:
+        jitlog.detach(sink)
+    fused = [e[5]["fused"] for e in sink.tracer.events()
+             if e[0] == "B" and e[1] == "prefill"]
+    assert fused == [True, True, True, True]
+
+
 def test_eos_exit_matches_whole_batch_trim(tiny_model):
     """A slot leaves the moment it emits EOS; the whole-batch path trims
     at the same token, so outputs still agree exactly."""
