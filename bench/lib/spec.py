@@ -1,11 +1,16 @@
 """Find a cell's data by name: BENCHMARK.json, configuration, traffic mix,
-peaks, per-layer metric readers and kernel cost functions.
+peaks, model family references, per-layer metric readers and kernel cost
+functions.
 
-Every piece that belongs to one configuration, one traffic mix, one
-per-layer metric or one kernel is a file of its own, found here by the
-name ``BENCHMARK.json`` gives it:
+Every piece that belongs to one configuration, one model family, one
+traffic mix, one per-layer metric or one kernel is a file of its own,
+found here by the name ``BENCHMARK.json`` or the configuration gives it:
 
-* ``bench/configs/<config>.json``   a model configuration;
+* ``bench/configs/<config>.json``   a model configuration, which names
+  its family in ``"reference"``;
+* ``bench/references/<family>.py``  a family's plain reference: seeded
+  scales, weights, forward and operation count (the interface is in
+  ``lib/reference.py``);
 * ``bench/traffic/<traffic>.json``  a traffic mix;
 * ``bench/metrics/<metric>.py``     a per-layer metric reader, ``read(ctx)``;
 * ``bench/kernels/<kernel>.py``     a kernel's operation and byte count.
@@ -62,6 +67,12 @@ def _module(path: Path, label: str) -> ModuleType:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def reference(name: str) -> ModuleType:
+    """The family file that a configuration's ``"reference"`` names."""
+    return _module(BENCH / "references" / f"{name}.py",
+                   "bench_reference_" + name.replace(".", "_"))
 
 
 def metric_reader(name: str) -> ModuleType:

@@ -7,12 +7,14 @@ The cell (``BENCHMARK.json``) names a configuration
 (``bench/traffic/<traffic>.json``).  The run builds the served RAG
 system with the program's own ``repro.launch.serve.build_served``: the
 document store, its passages and the weight matrices from the mix's
-corpus seed, the norm scales and QKV biases from ``--seed``.  It warms
+corpus seed, the norm scales and biases of the configuration's family
+(``bench/references/<family>.py``) from ``--seed``.  It warms
 up the shapes the mix uses, then a closed loop of clients drives
 ``RagdollEngine.submit`` to harvest for ``--seconds``, each client
 asking the seed's next job when its last one is harvested.  After the
 window it frees the program's state and checks what the window served
-against the plain reference (``bench/lib/reference.py``).
+against the plain reference (``bench/lib/reference.py`` and the
+family file).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics,
@@ -246,31 +248,58 @@ def warm_engine(drv: "Driver", mix, seed: int) -> None:
 # ---------------------------------------------------------------------------
 
 
+def written(tree: Dict[str, Any], new: Dict[str, Any], where: str
+            ) -> Dict[str, Any]:
+    """``tree`` with every leaf of ``new`` put at the same path, each into
+    the memory the program keeps the old leaf in (device, or pinned host
+    for a streamed layer).  A path ``tree`` lacks, or a leaf of another
+    shape or type, is an error: a seeded value never goes unwritten."""
+    import jax
+    out = dict(tree)
+    for key, val in new.items():
+        at = f"{where}/{key}"
+        if key not in out:
+            raise KeyError(f"the served weights have no {at}")
+        if isinstance(val, dict):
+            out[key] = written(out[key], val, at)
+            continue
+        old = out[key]
+        if (val.shape, val.dtype) != (old.shape, old.dtype):
+            raise ValueError(f"{at} is {old.dtype}{list(old.shape)} served, "
+                             f"{val.dtype}{list(val.shape)} seeded")
+        out[key] = jax.device_put(val, old.sharding)
+    return out
+
+
 def set_scales(served, sc: Dict[str, Any]) -> None:
-    """Write the seeded norm scales and QKV biases (``R.scales``) into
-    the served weights, each into the memory the program keeps it in
-    (device, or pinned host for a streamed layer)."""
+    """Write the family's seeded tree (``fam.scales``: ``top`` and one
+    tree per layer) into the served weights."""
     import jax
     ex = served.generator.exec
-
-    def put(new, old):
-        return jax.device_put(new, old.sharding)
-    ex.top = dict(ex.top, final_norm=put(sc["final_norm"],
-                                         ex.top["final_norm"]))
-    for i, (kind, lp) in enumerate(ex.layers):
-        lp = dict(lp, norm1=put(sc["norm1"][i], lp["norm1"]),
-                  norm2=put(sc["norm2"][i], lp["norm2"]))
-        if "bq" in sc:
-            lp["attn"] = dict(lp["attn"], **{
-                b: put(sc[b][i], lp["attn"][b]) for b in ("bq", "bk", "bv")})
-        ex.layers[i] = (kind, lp)
+    if len(sc["layers"]) != len(ex.layers):
+        raise ValueError(f"{len(sc['layers'])} layers seeded, "
+                         f"{len(ex.layers)} served")
+    ex.top = written(ex.top, sc["top"], "top")
+    for i, ((kind, lp), new) in enumerate(zip(ex.layers, sc["layers"])):
+        ex.layers[i] = (kind, written(lp, new, f"layers/{i}"))
     jax.block_until_ready((ex.top, ex.layers))
 
 
 def model_config(m: Dict[str, Any]):
-    from repro.configs.base import ModelConfig
+    """The program's ``ModelConfig`` of a configuration's ``model``
+    section; its ``moe``, ``mla`` and ``ssm`` sections become their own
+    configs, and any other section is an error."""
+    from repro.configs.base import MLAConfig, ModelConfig, MoEConfig, \
+        SSMConfig
+    sections = {"moe": MoEConfig, "mla": MLAConfig, "ssm": SSMConfig}
     kw = dict(m)
     kw["layer_pattern"] = tuple(tuple(k) for k in kw["layer_pattern"])
+    for key, val in kw.items():
+        if isinstance(val, dict):
+            if key not in sections:
+                raise ValueError(f"model section {key!r} is not one of "
+                                 f"{sorted(sections)}")
+            kw[key] = sections[key](**val)
     return ModelConfig(**kw)
 
 
@@ -351,6 +380,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
 
     m = conf["model"]
     cfg = model_config(m)
+    fam = S.reference(conf["reference"])
     corpus = mix["corpus"]
     if corpus["spilled"] != corpus["partitions"] // 2:
         raise ValueError("build_served spills half the partitions")
@@ -371,7 +401,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
             num_queries=pool, log=log, **(build_kw or {}))
         served.store.chunks = R.Passages(corpus["rows"], int(corpus["seed"]),
                                          int(corpus["passage_words"]))
-        set_scales(served, R.scales(m, seed))
+        set_scales(served, fam.scales(m, seed))
         ex = served.generator.exec
         log(f"built: {served.seconds}; {ex.resident}/{ex.n_layers} layers "
             f"resident, {ex.streamed_bytes} B streamed per step; "
@@ -393,7 +423,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
             warm_engine(drv, mix, seed)
             log(f"warm-up through the engine {time.perf_counter() - t:.1f} s")
             result = window(drv, mix, seed, seconds, trace, compiles,
-                            stop, conf, peaks)
+                            stop, conf, fam, peaks)
         finally:
             stop.set()
             eng.stop()
@@ -422,7 +452,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
 
 
 def window(drv: Driver, mix, seed, seconds, trace, compiles, stop, conf,
-           peaks) -> Dict[str, Any]:
+           fam, peaks) -> Dict[str, Any]:
     eng = drv.eng
     preroll = float(mix["preroll_s"])
     t0 = time.perf_counter() + preroll
@@ -467,7 +497,8 @@ def window(drv: Driver, mix, seed, seconds, trace, compiles, stop, conf,
     e2e = {"setup_s": (setup_s, "s"),
            "tokens_per_s": (tokens / seconds, "tokens/s")}
     ctx = {
-        "model": conf["model"], "mix": mix, "peaks": peaks,
+        "model": conf["model"], "reference": fam, "mix": mix,
+        "peaks": peaks,
         "requests": finished, "seconds": seconds,
         "steps": (h1[0] - h0[0], h1[1] - h0[1]),
         "streamed_bytes": int(eng.generator.exec.streamed_bytes),
@@ -558,6 +589,7 @@ def check(conf, mix, seed, sample, retrieved, control=False
     the same numbers for the control: retrieval scored in bfloat16, and
     the tokens an fp8 forward puts first."""
     m = conf["model"]
+    fam = S.reference(conf["reference"])
     lim = conf["limits"]
     corpus = mix["corpus"]
     cseed = int(corpus["seed"])
@@ -581,8 +613,8 @@ def check(conf, mix, seed, sample, retrieved, control=False
     served = [toks for _, _, toks in sample]
     cap = int(mix["answer"]["max"])
     seeds = (cseed, seed)
-    tgap = (R.token_gap(m, seeds, prompts, served, max_new=cap) if sample
-            else float("inf"))
+    tgap = (R.token_gap(fam, m, seeds, prompts, served, max_new=cap)
+            if sample else float("inf"))
     log(f"reference forward {time.perf_counter() - t:.1f} s")
     out = {"retrieval_gap": (rgap, lim["retrieval_gap"]),
            "token_logit_gap": (tgap, lim["token_logit_gap"])}
@@ -591,7 +623,7 @@ def check(conf, mix, seed, sample, retrieved, control=False
             vecs, qpool[[q for q, _ in retrieved]], None,
             int(mix["top_k"]), lowp=True), lim["retrieval_gap"])
         out["control.token_logit_gap"] = (R.token_gap(
-            m, seeds, prompts, served, precision="fp8", max_new=cap),
+            fam, m, seeds, prompts, served, precision="fp8", max_new=cap),
             lim["token_logit_gap"])
     return out
 

@@ -28,11 +28,11 @@ def _run(name, seed, tamper=None, control=False):
     mix = tiny.mix(cell["traffic"], check={"requests": 24, "tokens": 150})
     return B.run(name, seed, 4.0, False, cell=cell, conf=conf,
                  mix=mix, require_chip=False,
-                 build_kw=tiny.build_kw(name.startswith("mistral")),
+                 build_kw=tiny.build_kw(cell["config"]),
                  tamper=tamper, control=control)
 
 
-@pytest.mark.parametrize("name", ["chatglm3-6b.rag_backlog",
+@pytest.mark.parametrize("name", ["chatglm3-6b.rag_single",
                                   "mistral-7b-v0.3.rag_backlog"])
 def test_sound_run_is_correct_and_control_is_not(name):
     res = _run(name, 2 ** 31 + 7, control=True)
@@ -94,7 +94,7 @@ def _lose_norms(served):
                                            (_lose_biases, "token_logit_gap"),
                                            (_lose_norms, "token_logit_gap")])
 def test_broken_path_is_not_correct(tamper, number):
-    res = _run("chatglm3-6b.rag_backlog", 12345, tamper=tamper)
+    res = _run("chatglm3-6b.rag_single", 12345, tamper=tamper)
     assert not res["correct"]
     cmp = res["compared"][number]
     assert cmp["value"] > cmp["limit"]

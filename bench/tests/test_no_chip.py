@@ -16,7 +16,7 @@ RUN = Path(__file__).resolve().parents[1] / "run.py"
 def test_no_tpu_no_result():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run([sys.executable, str(RUN), "--workload",
-                        "chatglm3-6b.rag_backlog", "--seed", "3000000000",
+                        "chatglm3-6b.rag_single", "--seed", "3000000000",
                         "--seconds", "1", "--trace", "0"],
                        capture_output=True, text=True, env=env, timeout=300)
     assert p.returncode != 0

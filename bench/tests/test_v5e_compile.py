@@ -1,5 +1,6 @@
-"""Compile each cell's per-layer prefill and decode programs and its paged
-decode kernel for a described TPU v5e, at the cell's own sizes.
+"""Compile each cell's per-layer prefill program (the one a served join
+runs) and decode program, with its flash prefill and paged decode
+kernels, for a described TPU v5e, at the cell's own sizes.
 
 No chip is needed: the topology is described, not attached, and each
 program is compiled from shapes.  The program picks its Pallas kernels
@@ -19,7 +20,7 @@ from jax.sharding import SingleDeviceSharding
 import tiny  # noqa: F401  (puts bench/ and src/ on the path)
 from lib import spec as S
 
-CELLS = ["chatglm3-6b.rag_backlog", "mistral-7b-v0.3.rag_backlog"]
+CELLS = ["chatglm3-6b.rag_single", "mistral-7b-v0.3.rag_backlog"]
 
 
 @pytest.fixture(scope="module")
@@ -55,7 +56,8 @@ def _sds(tree, sharding):
 
 @pytest.mark.parametrize("name", CELLS)
 def test_layer_programs_compile(name, one_chip, tpu_kernels):
-    from repro.core.prefetch import layer_param_shapes, layer_program
+    from repro.core.prefetch import (layer_param_shapes, layer_program,
+                                     prefill_programs)
     from repro.models.model import _layer_cache_spec
     cfg, mix = _shapes(name)
     slots, ctx, page = mix["slots"], mix["ctx_len"], mix["page_size"]
@@ -63,17 +65,14 @@ def test_layer_programs_compile(name, one_chip, tpu_kernels):
     nmax = -(-total // page)
     kind = cfg.layer_pattern[0]
     lp = _sds(layer_param_shapes(cfg, jnp.bfloat16), one_chip)
-    row = _sds(_layer_cache_spec(cfg, kind[0], 1, total, jnp.bfloat16,
-                                 None), one_chip)
     pool = _sds(_layer_cache_spec(cfg, kind[0], slots * nmax + 1, page,
                                   jnp.bfloat16, None), one_chip)
 
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pre = layer_program(cfg, kind, "prefill").lower(
-        lp, arr((1, ctx, cfg.d_model), jnp.bfloat16), row, None,
-        None).compile()
+    pre = prefill_programs(cfg, kind, jnp.bfloat16)[1].lower(
+        lp, arr((1, ctx, cfg.d_model), jnp.bfloat16)).compile()
     dec = layer_program(cfg, kind, "decode", total).lower(
         lp, arr((slots, 1, cfg.d_model), jnp.bfloat16), pool,
         arr((slots,), jnp.int32), arr((slots, nmax), jnp.int32)).compile()
